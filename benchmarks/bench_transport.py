@@ -77,8 +77,12 @@ def _recv_proc(port: int, mode: str) -> None:
     Acks one byte once the reconstruction is complete.
     """
     from repro.core import CodecConfig, calibrate
+    from repro.hostenv import pin_cpu
     from repro.transport import FrameReader, TensorAssembler
 
+    # the decoder runs on the host: keep it off the chip the benchmark
+    # process may hold
+    pin_cpu()
     # warm the decode path (first-call jax dispatch) before signaling
     # ready, so the measured latency is steady-state codec work
     dummy = calibrate(CodecConfig(n_levels=8, clip_mode="manual",

@@ -313,8 +313,12 @@ def main():
             flags.append(f"--tls-key={args.tls_key}")
         if args.secret:
             flags.append(f"--secret={args.secret}")
+        # the cloud decodes on the host: keep it off the chip the edge
+        # process holds
+        from repro.hostenv import cpu_env
         cloud = subprocess.Popen(
-            [sys.executable, __file__, "--role=cloud"] + flags)
+            [sys.executable, __file__, "--role=cloud"] + flags,
+            env=cpu_env())
         try:
             deadline = time.time() + 60
             while time.time() < deadline:  # wait for the listener

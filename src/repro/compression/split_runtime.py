@@ -48,8 +48,7 @@ from jax.sharding import NamedSharding, PartitionSpec as P
 from ..configs.base import ModelConfig
 from ..core.codec import FeatureCodec
 from ..models import transformer as T
-from ..models.context import (DistContext, SHARD_MAP_PARTIAL_AUTO,
-                              shard_map)
+from ..models.context import DistContext, shard_map
 
 
 def split_supported(cfg: ModelConfig) -> bool:
@@ -99,21 +98,25 @@ def make_split_decode_step(cfg: ModelConfig, mesh, codec: FeatureCodec,
     but full-width transfer, the ablation), or 'raw' (bf16 baseline).
     """
     assert "pod" in mesh.axis_names, "split runtime needs the multi-pod mesh"
-    # Sharding-constraint hints inside the manual 'pod' region need the
-    # partially-automatic region mode; fully-manual regions reject
-    # full-mesh NamedShardings.  They are perf hints, not correctness,
-    # so the fully-manual path drops them.
-    inner_ctx = DistContext(mesh, ("data",)) if SHARD_MAP_PARTIAL_AUTO \
-        else None
+    # sharding hints inside the region name the auto (non-'pod') axes
+    inner_ctx = DistContext(mesh, ("data",))
     half, tail = stage_layout(cfg)
     d = cfg.d_model
+    # GSPMD cannot partition a Pallas (Mosaic) call, so the codec runs in
+    # a nested region manual over the auto axes too: each device of a pod
+    # codes the whole (small) boundary tensor, replicated
+    rest = frozenset(mesh.axis_names) - {"pod"}
+
+    def local(fn):
+        if not rest:
+            return fn
+        # nested inside the 'pod' region: map over its (abstract) mesh
+        return shard_map(fn, jax.sharding.get_abstract_mesh(), P(), P(),
+                         manual_axes=rest)
 
     def body(pod_arr, stages, tail_p, embed, final_norm, head, token,
              stage_cache, tail_cache, pos):
-        # pod identity arrives as a pod-sharded iota instead of
-        # lax.axis_index: identical value, but it avoids the PartitionId
-        # instruction that pre-0.6 XLA SPMD rejects under auto axes.
-        pod = pod_arr[0]
+        pod = pod_arr[0]                # pod-sharded iota: this pod's id
         my_layers = jax.tree.map(lambda a: a[0], stages)  # (half, ...)
         base = {"embed": embed, "final_norm": final_norm}
         if head is not None:
@@ -132,16 +135,23 @@ def make_split_decode_step(cfg: ModelConfig, mesh, codec: FeatureCodec,
             rate_bits = jnp.float32(jnp.finfo(jnp.bfloat16).bits)
         else:
             # backend-dispatched: fused Pallas clip+quant on TPU, jnp on CPU
-            idx = codec.quantize(y_a)
-            if transport == "packed":
-                payload = codec.pack(idx.reshape(-1))
-                recv = lax.ppermute(payload, "pod", [(0, 1)])
-                idx_r = codec.unpack(recv, idx.size).reshape(idx.shape)
-            else:  # quantized transfer at full index width
-                recv = lax.ppermute(idx, "pod", [(0, 1)])
-                idx_r = recv
-            x_b = codec.dequantize(idx_r, dtype=y_a.dtype)
-            rate_bits = codec.rate_from_indices(idx, idx.shape)
+            shape = y_a.shape
+
+            def encode(y):
+                idx = codec.quantize(y)
+                wire = codec.pack(idx.reshape(-1)) \
+                    if transport == "packed" else idx
+                return wire, codec.rate_from_indices(idx, shape)
+
+            def decode(recv):
+                idx_r = codec.unpack(recv, y_a.size).reshape(shape) \
+                    if transport == "packed" else recv
+                return codec.dequantize(idx_r, dtype=y_a.dtype)
+
+            # 'packed' sends uint8 lanes; otherwise the quantized transfer
+            # at full index width
+            wire, rate_bits = local(encode)(y_a)
+            x_b = local(decode)(lax.ppermute(wire, "pod", [(0, 1)]))
 
         # ---- superstep B: cloud half ----
         x_in_b = jnp.where(pod == 1, x_b, x)

@@ -166,14 +166,18 @@ def _coded_order_device(q, spec: QuantSpec):
 
 
 def _unpack_bytes_device(packed, bits: int):
-    """In-graph mirror of ``ops.unpack_bytes`` (uint8 -> int32 indices)."""
+    """In-graph mirror of ``ops.unpack_bytes`` ((R // per, C) uint8 byte
+    rows -> (R, C) int32 indices)."""
+    from ..kernels.ops import packed_block_rows
     per = 8 // bits if bits in (1, 2, 4) else 1
     if per == 1:
         return packed.astype(jnp.int32)
-    shifts = (jnp.arange(per, dtype=jnp.uint8) * bits)[None, :]
+    rp, c = packed.shape
+    s = packed_block_rows(rp, per)
+    shifts = (jnp.arange(per, dtype=jnp.uint8) * bits)[None, :, None, None]
     mask = jnp.uint8((1 << bits) - 1)
-    vals = (packed.reshape(-1, 1) >> shifts) & mask
-    return vals.reshape(packed.shape[:-1] + (-1,)).astype(jnp.int32)
+    vals = (packed.reshape(-1, 1, s, c) >> shifts) & mask
+    return vals.reshape(rp * per, c).astype(jnp.int32)
 
 
 def _unpack_layout_device(idx2d, lay):
@@ -196,8 +200,6 @@ def _encode_wire(coded, spec: QuantSpec, chunk_bounds, *, use_kernel: bool,
     """Device entropy stage: coded-order indices (on device) -> finished
     coder-id-4 payload bytes (one, or one per ``chunk_bounds`` range)."""
     from ..kernels import rans_coder
-    if interpret is None:
-        interpret = jax.default_backend() == "cpu"
     if chunk_bounds is None:
         return rans_coder.encode_indices_device(
             coded, spec.n_levels, use_kernel=use_kernel, interpret=interpret)
@@ -400,7 +402,10 @@ class KernelBackend:
     name = "kernel"
 
     def __init__(self, interpret: bool | None = None) -> None:
-        self.interpret = interpret
+        # None = interpret exactly when JAX runs on the CPU, resolved here
+        # so callers can check which one this backend runs
+        self.interpret = (jax.default_backend() == "cpu"
+                          if interpret is None else interpret)
         self._jnp = JnpBackend()
 
     def quantize(self, x, spec: QuantSpec):

@@ -149,10 +149,13 @@ def proc_map(fn, items, n_procs: int | None = None) -> list:
             _shutdown_proc_pool()
             # spawn, not fork: the parent typically has jax's thread
             # pools running, and forking a multithreaded process can
-            # deadlock; spawn pays a one-off worker import instead
+            # deadlock; spawn pays a one-off worker import instead.  The
+            # workers import jax with this package but run numpy only:
+            # pin_cpu keeps them off the parent's accelerator
+            from ..hostenv import pin_cpu
             _PROC_POOL = ProcessPoolExecutor(
                 max_workers=n, mp_context=multiprocessing.get_context(
-                    "spawn"))
+                    "spawn"), initializer=pin_cpu)
             _PROC_SIZE = n
         return list(_PROC_POOL.map(fn, items))
     except Exception:

@@ -139,17 +139,17 @@ def _kernel_encode(x_ref, cmin_ref, cmax_ref, valid_ref, packed_ref,
     as in :func:`_kernel_tiles`; the scalar per-tensor mode is the
     constant-range one-band case.
 
-    Packing combines ``per = 8 // bits`` adjacent lane values into one
-    byte (same little-end-first layout as ``pack_bits.py`` / the jnp host
-    fallback) via a minor-dim reshape; ``per == 1`` (bit widths 3/5/6)
-    stores one index per byte.  The histogram masks band-column padding
-    against the band's valid count (the (1, 1) ``valid_ref`` cell the
-    grid mapped for this band -- 2-D plans have ragged edge tiles, so
-    every band carries its own count) so tiles see only real elements;
-    padded rows are dropped host-side.  Like the rest of the kernel
-    backend this is validated in interpret mode in CI; the TPU lowering
-    of the lane-dim reshape is part of the ROADMAP's TPU-validation
-    follow-up.
+    Packing combines ``per = 8 // bits`` rows of the block into one byte
+    row: the (br, bc) block splits into ``per`` contiguous (br // per, bc)
+    row slabs, and slab ``k`` lands at bit offset ``k * bits``
+    (``ops.unpack_bytes`` inverts it).  Whole-slab sublane slices keep
+    the lane layout intact, which Mosaic lowers; a lane-dim reshape it
+    does not.  ``per == 1`` (bit widths 3/5/6) stores one index per
+    byte.  The histogram masks band-column padding against the band's valid
+    count (the (1, 1) ``valid_ref`` cell the grid mapped for this band --
+    2-D plans have ragged edge tiles, so every band carries its own
+    count) so tiles see only real elements; padded rows are dropped
+    host-side.
     """
     per = 8 // bits if bits in (1, 2, 4) else 1
     j = pl.program_id(1)
@@ -165,10 +165,10 @@ def _kernel_encode(x_ref, cmin_ref, cmax_ref, valid_ref, packed_ref,
     if per == 1:
         packed_ref[...] = q
     else:
-        q3 = q.reshape(q.shape[0], q.shape[1] // per, per)
-        acc = q3[:, :, 0]
+        s = q.shape[0] // per
+        acc = q[:s]
         for k in range(1, per):                 # unrolled: per in (2, 4, 8)
-            acc = acc + (q3[:, :, k] << (k * bits))
+            acc = acc + (q[k * s:(k + 1) * s] << (k * bits))
         packed_ref[...] = acc
 
     @pl.when(band_col == 0)
@@ -215,7 +215,8 @@ def encode_tiles_2d(x, cmin, cmax, n_levels: int, bits: int, sb_cols: int,
     tail may be padding when the spatial extent is not a block
     multiple); ``band_valid`` (n_sblocks,) overrides both with explicit
     per-band counts (2-D plans: ragged edge tiles).  Returns
-    (packed (R, C // per) int32 byte values,
+    (packed (R // per, C) int32 byte values, each ``block[0]``-row block
+    packed into ``block[0] // per`` rows,
     hist (R, n_sblocks * HIST_WIDTH) int32).
     """
     if n_levels > HIST_WIDTH:
@@ -242,10 +243,10 @@ def encode_tiles_2d(x, cmin, cmax, n_levels: int, bits: int, sb_cols: int,
                   pl.BlockSpec((br, 1), lambda i, j: (i, j * bc
                                                       // sb_cols)),
                   pl.BlockSpec((1, 1), lambda i, j, bpb=bpb: (0, j // bpb))],
-        out_specs=[pl.BlockSpec((br, bc // per), lambda i, j: (i, j)),
+        out_specs=[pl.BlockSpec((br // per, bc), lambda i, j: (i, j)),
                    pl.BlockSpec((br, HIST_WIDTH),
                                 lambda i, j, bpb=bpb: (i, j // bpb))],
-        out_shape=[jax.ShapeDtypeStruct((r, c // per), jnp.int32),
+        out_shape=[jax.ShapeDtypeStruct((r // per, c), jnp.int32),
                    jax.ShapeDtypeStruct((r, n_sblocks * HIST_WIDTH),
                                         jnp.int32)],
         interpret=interpret,

@@ -18,8 +18,9 @@ from ..core.tiling import PaddedLayout, TilePlan
 from . import ref
 from .ecsq_assign import ecsq_assign_2d, ecsq_assign_tiles_2d
 from .ecsq_assign import MAX_LEVELS as ECSQ_MAX_LEVELS
-from .fused_clip_quant import (HIST_WIDTH, clip_quant_2d, clip_quant_rows_2d,
-                               clip_quant_tiles_2d, encode_tiles_2d)
+from .fused_clip_quant import (DEFAULT_BLOCK, HIST_WIDTH, clip_quant_2d,
+                               clip_quant_rows_2d, clip_quant_tiles_2d,
+                               encode_tiles_2d)
 from .pack_bits import pack_rows_2d
 from .rate_hist import index_histogram_2d, index_histogram_tiles_2d
 
@@ -291,18 +292,29 @@ def encode_fused(x, lo, hi, *, n_levels: int, bits: int,
     return packed, hist, lay
 
 
+def packed_block_rows(packed_rows: int, per: int) -> int:
+    """Packed rows per kernel row block of the fused encode: the (R, C)
+    view runs in blocks of ``min(256, R)`` rows, each packed into
+    ``1 / per`` as many byte rows."""
+    return min(DEFAULT_BLOCK[0], packed_rows * per) // per
+
+
 def unpack_bytes(packed: np.ndarray, bits: int) -> np.ndarray:
-    """Host inverse of the kernel bit-pack: uint8 byte values -> int32
-    indices, ``per = 8 // bits`` per byte (identity for ``per == 1``).
-    Same little-end-first lane layout as ``FeatureCodec.unpack``."""
+    """Host inverse of the megakernel bit-pack: (R // per, C) uint8 byte
+    rows -> (R, C) int32 indices, ``per = 8 // bits`` per byte (identity
+    for ``per == 1``).  Within each row block, byte row ``r`` holds the
+    block's rows ``r + k * s`` at bit offset ``k * bits``, with ``s`` the
+    block's packed row count (see ``_kernel_encode``)."""
     packed = np.asarray(packed, np.uint8)
     per = 8 // bits if bits in (1, 2, 4) else 1
     if per == 1:
         return packed.astype(np.int32)
-    shifts = (np.arange(per, dtype=np.uint8) * bits)[None, :]
+    rp, c = packed.shape
+    s = packed_block_rows(rp, per)
+    shifts = (np.arange(per, dtype=np.uint8) * bits)[None, :, None, None]
     mask = np.uint8((1 << bits) - 1)
-    vals = (packed.reshape(-1, 1) >> shifts) & mask
-    return vals.reshape(packed.shape[:-1] + (-1,)).astype(np.int32)
+    vals = (packed.reshape(-1, 1, s, c) >> shifts) & mask
+    return vals.reshape(rp * per, c).astype(np.int32)
 
 
 @functools.partial(jax.jit, static_argnames=("n_levels", "plan",

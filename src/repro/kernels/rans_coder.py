@@ -42,8 +42,9 @@ TPU vector lane wants.  Two interchangeable step-loop implementations:
   * :func:`_step_loop_jnp` -- a ``lax.while_loop`` over steps, used by
     the jnp backend (and as the reference for the kernel);
   * :func:`_step_loop_pallas` -- a Pallas kernel with a sequential grid
-    over steps and the (1, lanes) state vector carried in a revisited
-    output block, used by the kernel backend (interpret mode on CPU).
+    over blocks of 8 steps and the (1, lanes) state vector carried in a
+    revisited output block, used by the kernel backend (interpret mode
+    on CPU).
 
 Per stream the stage is a size pre-pass (one reduction; the lane count
 and buffer buckets derive from it, so it has to reach the host first),
@@ -86,6 +87,13 @@ def _d2h_counter():
         "bytes fetched device->host by the encode path (wire payloads, "
         "probability side info and state flushes on the device-entropy "
         "path; full packed-index tensors on the host-coder path)")
+
+
+def host_fallback_counter():
+    return default_registry().counter(
+        "repro_codec_host_rans_fallback_total",
+        "coder-id-4 payloads coded by the host step loop because the "
+        "device stage cannot take their shape (level count or size)")
 
 
 def device_supported(n: int, n_levels: int) -> bool:
@@ -133,9 +141,14 @@ def _round_half_even_div(ones, sizes):
 
 
 def _iscan(v):
-    """Inclusive int32 prefix sum (associative_scan lowers to log-depth
-    passes, ~2x faster than the serial cumsum lowering on CPU)."""
-    return jax.lax.associative_scan(jnp.add, v.astype(jnp.int32))
+    """Inclusive int32 prefix sum.  On CPU associative_scan lowers to
+    log-depth passes, ~2x faster than the serial cumsum lowering; on TPU
+    the same scan over 2^20 elements takes minutes to compile, while
+    cumsum lowers to one reduce-window and compiles in seconds."""
+    v = v.astype(jnp.int32)
+    return jax.lax.platform_dependent(
+        v, cpu=lambda a: jax.lax.associative_scan(jnp.add, a),
+        default=lambda a: jnp.cumsum(a, dtype=jnp.int32))
 
 
 def _build_planes(coded, meta, n_levels: int, lanes: int, caps, t_cap: int,
@@ -262,66 +275,80 @@ def _step_loop_jnp(bits2d, f1_steps, total_steps, lanes: int, t_cap: int):
     return x, ov, w
 
 
+_STEP_ROWS = 8      # step-matrix rows per grid step: the int32 sublane tile
+
+
 def _rans_step_kernel(ns_ref, bits_ref, f1_ref, x_ref, ov_ref, w_ref, *,
-                      t_cap: int):
-    """One grid step codes one (reversed) row of the step matrix.
+                      n_blocks: int):
+    """One grid step codes one (reversed) block of ``_STEP_ROWS`` rows of
+    the step matrix, last row first.
 
     The grid is sequential, so the (1, lanes) state block -- an output
     revisited by every step -- carries the per-lane coder states across
-    iterations; rows past the stream's dynamic step count are skipped
-    (their output rows are zeroed so the word compaction can treat the
-    full static buffer uniformly).
+    iterations; rows past the stream's dynamic step count (``ns_ref``,
+    a prefetched SMEM scalar) are skipped, and their output rows are
+    zeroed so the word compaction can treat the full static buffer
+    uniformly.  Blocks of whole sublane tiles are what the TPU lowering
+    takes; a one-row block is not.
     """
-    i = pl.program_id(0)
-    t = t_cap - 1 - i
+    block = n_blocks - 1 - pl.program_id(0)
+    n_steps = ns_ref[0]
     u = jnp.uint32
 
-    @pl.when(i == 0)
+    @pl.when(pl.program_id(0) == 0)
     def _init():
         x_ref[...] = jnp.full(x_ref.shape, _STATE_LO, jnp.uint32)
 
-    n_steps = ns_ref[0, 0]
+    for r in reversed(range(_STEP_ROWS)):       # unrolled, reverse order
+        row = pl.ds(r, 1)
 
-    @pl.when(t < n_steps)
-    def _code():
-        x = x_ref[...]                                   # (1, lanes)
-        f1 = f1_ref[0, 0].astype(jnp.uint32)
-        f0 = u(_M) - f1
-        bb = bits_ref[...].astype(jnp.uint32)
-        f = jnp.where(bb == 1, f1, f0)
-        over = x >= (f << u(18))
-        w_ref[...] = (x & u(0xFFFF)).astype(jnp.int32)
-        x = jnp.where(over, x >> u(16), x)
-        q = x // f
-        x_ref[...] = (q << u(_PROB_BITS)) + (x - q * f) + f0 * bb
-        ov_ref[...] = over.astype(jnp.int32)
+        @pl.when(block * _STEP_ROWS + r < n_steps)
+        def _code():
+            x = x_ref[...]                              # (1, lanes)
+            f1 = f1_ref[row, :].astype(jnp.uint32)      # (1, 1)
+            f0 = u(_M) - f1
+            bb = bits_ref[row, :].astype(jnp.uint32)
+            f = jnp.where(bb == 1, f1, f0)
+            over = x >= (f << u(18))
+            w_ref[row, :] = (x & u(0xFFFF)).astype(jnp.int32)
+            x = jnp.where(over, x >> u(16), x)
+            q = x // f
+            x_ref[...] = (q << u(_PROB_BITS)) + (x - q * f) + f0 * bb
+            ov_ref[row, :] = over.astype(jnp.int32)
 
-    @pl.when(t >= n_steps)
-    def _skip():
-        ov_ref[...] = jnp.zeros(ov_ref.shape, jnp.int32)
-        w_ref[...] = jnp.zeros(w_ref.shape, jnp.int32)
+        @pl.when(block * _STEP_ROWS + r >= n_steps)
+        def _skip():
+            ov_ref[row, :] = jnp.zeros((1, ov_ref.shape[1]), jnp.int32)
+            w_ref[row, :] = jnp.zeros((1, w_ref.shape[1]), jnp.int32)
 
 
 def _step_loop_pallas(bits2d, f1_steps, total_steps, lanes: int,
                       t_cap: int, interpret: bool):
-    rev = lambda i: (t_cap - 1 - i, 0)  # noqa: E731
+    from jax.experimental.pallas import tpu as pltpu
+    n_blocks = -(-t_cap // _STEP_ROWS)
+    t_pad = n_blocks * _STEP_ROWS
+    bits = jnp.zeros((t_pad, lanes), jnp.int32).at[:t_cap].set(
+        bits2d.astype(jnp.int32))
+    f1 = jnp.zeros((t_pad, 1), jnp.int32).at[:t_cap, 0].set(
+        f1_steps.astype(jnp.int32))
+    rev = lambda i, ns: (n_blocks - 1 - i, 0)  # noqa: E731
     x, ov, w = pl.pallas_call(
-        functools.partial(_rans_step_kernel, t_cap=t_cap),
-        grid=(t_cap,),
-        in_specs=[pl.BlockSpec((1, 1), lambda i: (0, 0)),
-                  pl.BlockSpec((1, lanes), rev),
-                  pl.BlockSpec((1, 1), rev)],
-        out_specs=[pl.BlockSpec((1, lanes), lambda i: (0, 0)),
-                   pl.BlockSpec((1, lanes), rev),
-                   pl.BlockSpec((1, lanes), rev)],
+        functools.partial(_rans_step_kernel, n_blocks=n_blocks),
+        grid_spec=pltpu.PrefetchScalarGridSpec(
+            num_scalar_prefetch=1,
+            grid=(n_blocks,),
+            in_specs=[pl.BlockSpec((_STEP_ROWS, lanes), rev),
+                      pl.BlockSpec((_STEP_ROWS, 1), rev)],
+            out_specs=[pl.BlockSpec((1, lanes), lambda i, ns: (0, 0)),
+                       pl.BlockSpec((_STEP_ROWS, lanes), rev),
+                       pl.BlockSpec((_STEP_ROWS, lanes), rev)]),
         out_shape=[jax.ShapeDtypeStruct((1, lanes), jnp.uint32),
-                   jax.ShapeDtypeStruct((t_cap, lanes), jnp.int32),
-                   jax.ShapeDtypeStruct((t_cap, lanes), jnp.int32)],
+                   jax.ShapeDtypeStruct((t_pad, lanes), jnp.int32),
+                   jax.ShapeDtypeStruct((t_pad, lanes), jnp.int32)],
         interpret=interpret,
-    )(total_steps.reshape(1, 1).astype(jnp.int32),
-      bits2d.astype(jnp.int32),
-      f1_steps.reshape(t_cap, 1).astype(jnp.int32))
-    return x.reshape(lanes), ov.astype(jnp.int8), w.astype(jnp.uint16)
+    )(total_steps.reshape(1).astype(jnp.int32), bits, f1)
+    return (x.reshape(lanes), ov[:t_cap].astype(jnp.int8),
+            w[:t_cap].astype(jnp.uint16))
 
 
 @functools.partial(jax.jit,
@@ -455,6 +482,7 @@ def encode_indices_device(coded, n_levels: int, *, use_kernel: bool = False,
         return cabac.wrap_device_blob(b"")
     if not device_supported(n, n_levels):
         from ..core.binarization import index_to_context_bits
+        host_fallback_counter().inc()
         blob = rans.encode_planes(
             index_to_context_bits(np.asarray(coded).ravel(), n_levels))
     else:
@@ -476,7 +504,7 @@ def encode_index_chunks_device(coded, n_levels: int, bounds, *,
 
 def dispatch_index_chunks(coded, n_levels: int, bounds, *,
                           use_kernel: bool = False,
-                          interpret: bool | None = None):
+                          interpret: bool = False):
     """Async phase of :func:`encode_index_chunks_device`: launch every
     chunk's entropy stage and return an opaque pending list.
 
@@ -488,11 +516,10 @@ def dispatch_index_chunks(coded, n_levels: int, bounds, *,
     already-finished payloads).
     """
     from ..core import cabac
-    if interpret is None:
-        interpret = jax.default_backend() == "cpu"
     n = int(coded.shape[0]) if hasattr(coded, "shape") else len(coded)
     if not device_supported(n, n_levels):
         from ..core.binarization import index_to_context_bits
+        host_fallback_counter().inc(len(bounds))
         host = np.asarray(coded).ravel()
         return [("host", cabac.wrap_device_blob(
             b"" if s >= e else rans.encode_planes(

@@ -63,6 +63,8 @@ def main(argv=None) -> int:
                     help="write the full JSON report here")
     args = ap.parse_args(argv)
 
+    from .compile_cache import enable_compile_cache
+    enable_compile_cache()
     scenarios = load_matrix(args.matrix)
     out: dict = {"matrix": [sc.name for sc in scenarios]}
     if args.select:

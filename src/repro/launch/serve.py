@@ -212,12 +212,14 @@ def _loopback_codec_fn(codec, chunk_elems: int, tick_ms: float = 0.0,
     return host_roundtrip, cleanup
 
 
-def main():
+def build_parser() -> argparse.ArgumentParser:
     ap = argparse.ArgumentParser()
     ap.add_argument("--arch", required=True)
     ap.add_argument("--requests", type=int, default=8)
     ap.add_argument("--prompt-len", type=int, default=16)
     ap.add_argument("--new-tokens", type=int, default=16)
+    ap.add_argument("--seed", type=int, default=0,
+                    help="seed of the random weights and request prompts")
     ap.add_argument("--codec-levels", type=int, default=0,
                     help="0 = no split codec; else N quantizer levels")
     ap.add_argument("--clip-mode", default="model",
@@ -277,8 +279,10 @@ def main():
                     help="enable pipeline stage tracing and mirror the "
                          "JSON span log to PATH")
     ap.add_argument("--full", action="store_true")
-    args = ap.parse_args()
+    return ap
 
+
+def _check_args(ap: argparse.ArgumentParser, args) -> None:
     if args.metrics_port is not None and args.transport != "loopback":
         ap.error("--metrics-port needs --transport loopback")
     if args.transport != "loopback":
@@ -288,6 +292,8 @@ def main():
                           ("--secret", args.secret is not None)):
             if val:
                 ap.error(f"{flag} needs --transport loopback")
+    if args.transport == "loopback" and not args.codec_levels:
+        ap.error("--transport loopback needs --codec-levels")
     if args.workers < 1:
         ap.error("--workers must be >= 1")
     if args.tls_key is not None and args.tls_cert is None:
@@ -295,25 +301,26 @@ def main():
     if args.workers > 1 and args.metrics_port is not None:
         ap.error("--metrics-port is per-worker; not supported with "
                  "--workers > 1 (scrape the dispatcher registry instead)")
-    if args.trace is not None:
-        from ..obs import configure_tracing
-        configure_tracing(enabled=True, event_log_path=args.trace)
-        print(f"stage tracing on: span log -> {args.trace}")
 
+
+def serve(cfg, args, params=None) -> dict:
+    """Serve ``args.requests`` random prompts on the model ``cfg``.
+
+    The body of ``main`` with the configuration passed in: weights are
+    drawn from ``args.seed`` unless ``params`` are given.  Returns the
+    pieces a caller checks -- ``params``, the calibrated ``codec`` (None
+    without ``--codec-levels``), the ``engine`` and its served
+    ``requests``.
+    """
     import jax
 
-    from ..configs import get_config, reduced
     from ..models import init_params
     from ..serving import Request, ServeEngine
 
-    cfg = get_config(args.arch)
-    if not args.full:
-        cfg = reduced(cfg)
-    params = init_params(cfg, jax.random.PRNGKey(0))
+    if params is None:
+        params = init_params(cfg, jax.random.PRNGKey(args.seed))
 
-    codec = None
-    codec_host_fn = None
-    cleanup = None
+    codec = codec_host_fn = cleanup = None
     if args.codec_levels:
         codec = _calibrate_warmup(cfg, params, args)
         if args.transport == "loopback":
@@ -323,21 +330,23 @@ def main():
                 workers=args.workers, max_queue=args.max_queue,
                 tls_cert=args.tls_cert, tls_key=args.tls_key,
                 secret=args.secret)
-            codec = None
-    elif args.transport == "loopback":
-        ap.error("--transport loopback needs --codec-levels")
 
     eng = ServeEngine(cfg, params, slots=4,
                       max_seq=args.prompt_len + args.new_tokens + 8,
-                      codec=codec, codec_host_fn=codec_host_fn)
-    rng = np.random.default_rng(0)
+                      codec=None if codec_host_fn else codec,
+                      codec_host_fn=codec_host_fn)
+    rng = np.random.default_rng(args.seed)
     reqs = [Request(prompt=rng.integers(0, cfg.vocab_size,
                                         size=args.prompt_len).astype(np.int32),
                     max_new_tokens=args.new_tokens)
             for _ in range(args.requests)]
-    t0 = time.time()
-    eng.generate(reqs)
-    dt = time.time() - t0
+    try:
+        t0 = time.time()
+        eng.generate(reqs)
+        dt = time.time() - t0
+    finally:
+        if cleanup is not None:
+            cleanup()
     total = sum(len(r.out_tokens) for r in reqs)
     print(f"{total} tokens in {dt:.2f}s = {total / dt:.1f} tok/s "
           f"({args.requests} requests)")
@@ -356,8 +365,27 @@ def main():
     if args.codec_levels:
         from ..transport import bank_cache_stats
         print(f"codec bank cache: {bank_cache_stats()}")
-    if cleanup is not None:
-        cleanup()
+    return {"params": params, "codec": codec, "engine": eng,
+            "requests": reqs}
+
+
+def main(argv=None):
+    ap = build_parser()
+    args = ap.parse_args(argv)
+    _check_args(ap, args)
+    if args.trace is not None:
+        from ..obs import configure_tracing
+        configure_tracing(enabled=True, event_log_path=args.trace)
+        print(f"stage tracing on: span log -> {args.trace}")
+
+    from ..configs import get_config, reduced
+    from .compile_cache import enable_compile_cache
+
+    enable_compile_cache()
+    cfg = get_config(args.arch)
+    if not args.full:
+        cfg = reduced(cfg)
+    serve(cfg, args)
 
 
 if __name__ == "__main__":

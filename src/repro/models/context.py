@@ -5,22 +5,6 @@ from __future__ import annotations
 import dataclasses
 from typing import Any
 
-# Single feature detect for the shard_map API split.  jax >= 0.6 promotes
-# shard_map to ``jax.shard_map`` and supports partially-manual regions
-# (``axis_names=``, other mesh axes left to GSPMD); the 0.4.x/0.5.x line
-# only has the fully-manual ``jax.experimental.shard_map.shard_map``.
-# Every shard_map entry in the repo routes through :func:`shard_map`
-# below, and SHARD_MAP_PARTIAL_AUTO is the one capability flag callers
-# may branch on (the split runtime keys its in-region sharding hints off
-# it) -- no other module should feature-detect jax versions itself.
-try:
-    from jax import shard_map as _native_shard_map  # jax >= 0.6
-    SHARD_MAP_PARTIAL_AUTO = True
-except ImportError:  # pragma: no cover - exercised on the 0.4.x line
-    from jax.experimental.shard_map import shard_map as _legacy_shard_map
-    _native_shard_map = None
-    SHARD_MAP_PARTIAL_AUTO = False
-
 
 @dataclasses.dataclass(frozen=True)
 class DistContext:
@@ -88,21 +72,13 @@ def shard_map(body, mesh, in_specs, out_specs, *, manual_axes=None):
     """The repo's one shard_map entry point.
 
     ``manual_axes=None`` maps fully manually over every mesh axis.  A
-    frozenset (e.g. ``{'pod'}``) requests a partially-manual region with
-    the remaining axes left automatic -- honoured when
-    :data:`SHARD_MAP_PARTIAL_AUTO` is set (jax >= 0.6); the legacy line
-    runs the body fully manual instead, which is equivalent for
-    replicated in_specs (each device holds the full operand and simply
-    runs the body replicated across the non-manual axes).
-    ``check_rep=False`` on the legacy call skips the stricter
-    replication verifier 0.4.x applies to psum outputs.
+    set (e.g. ``{'pod'}``) makes a partially-manual region with the
+    remaining axes left automatic (the mesh's axes must be ``Auto``; see
+    ``launch.mesh.make_mesh``).
     """
-    if _native_shard_map is not None:
-        kwargs = {}
-        if manual_axes is not None:
-            kwargs = dict(axis_names=frozenset(manual_axes),
-                          check_vma=False)
-        return _native_shard_map(body, mesh=mesh, in_specs=in_specs,
-                                 out_specs=out_specs, **kwargs)
-    return _legacy_shard_map(body, mesh=mesh, in_specs=in_specs,
-                             out_specs=out_specs, check_rep=False)
+    import jax
+    kwargs = {}
+    if manual_axes is not None:
+        kwargs = dict(axis_names=frozenset(manual_axes), check_vma=False)
+    return jax.shard_map(body, mesh=mesh, in_specs=in_specs,
+                         out_specs=out_specs, **kwargs)

@@ -329,7 +329,7 @@ def encode_tick(items, cfg: TickConfig = TickConfig()
             pend = [rans_coder.dispatch_index_chunks(
                 coded[i], codec.config.n_levels, bounds_per[i],
                 use_kernel=codec.backend.name == "kernel",
-                interpret=getattr(codec.backend, "interpret", None))
+                interpret=getattr(codec.backend, "interpret", False))
                 for i, (codec, _) in enumerate(items)]
             blobs = [b for p in pend
                      for b in rans_coder.finalize_index_chunks(p)]

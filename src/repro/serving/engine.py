@@ -102,6 +102,7 @@ class ServeEngine:
         self.slots = slots
         self.max_seq = max_seq
         self.refill_align = max(1, refill_align)
+        self._finite = True
         self.rate_log: collections.deque = collections.deque(maxlen=1 << 16)
         self.latency_log: collections.deque = collections.deque(
             maxlen=max(1, latency_log_size))
@@ -185,6 +186,7 @@ class ServeEngine:
                     f"request needs {len(r.prompt) + r.max_new_tokens} "
                     f"cache positions, engine has max_seq={self.max_seq}")
         queue = list(requests)
+        self._finite = True
         active: list[Request | None] = [None] * self.slots
         cache = None
         cur = None          # (slots,) next token per slot
@@ -222,9 +224,16 @@ class ServeEngine:
                 bpe = float(aux["codec_rate_bits"])
                 self.rate_log.append(bpe)
                 self._m_bpe.observe(bpe)
+            self._finite &= jnp.all(jnp.isfinite(lg))
             cur = jnp.argmax(lg, axis=-1).astype(jnp.int32)
             pos += 1
         return requests
+
+    @property
+    def logits_finite(self) -> bool:
+        """Were all logits of the last ``generate`` finite?  (Reduced on
+        device as the steps run; reading it syncs once.)"""
+        return bool(self._finite)
 
     def _retire(self, active: list, i: int) -> None:
         r = active[i]
@@ -286,6 +295,7 @@ class ServeEngine:
         with span("prefill", batch=len(batch)):
             logits, cache = self._prefill(self.params, jnp.asarray(toks),
                                           cache)
+        self._finite &= jnp.all(jnp.isfinite(logits))
         cur = jnp.argmax(logits, axis=-1).astype(jnp.int32)
         # zero-token requests retire immediately
         for i, r in enumerate(batch):
@@ -325,6 +335,7 @@ class ServeEngine:
         self._m["prefills"].inc()
         with span("prefill", batch=1, refill=True):
             logits, one = self._prefill(self.params, jnp.asarray(toks), one)
+        self._finite &= jnp.all(jnp.isfinite(logits))
         cache = jax.tree.map(lambda full, o: full.at[:, slot].set(o[:, 0]),
                              cache, one)
         first = jnp.argmax(logits[0]).astype(jnp.int32)

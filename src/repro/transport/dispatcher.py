@@ -53,6 +53,7 @@ import signal
 import subprocess
 import time
 
+from ..hostenv import cpu_env
 from ..obs.metrics import MetricsRegistry
 from .errors import (E_BUSY, E_PROTOCOL, E_SHUTDOWN, E_UNAUTHORIZED,
                      E_WORKER_RESTART, encode_error)
@@ -299,8 +300,11 @@ class Dispatcher:
         else:
             cmd = list(self._worker_cmd) + ["--host", "127.0.0.1",
                                             "--port", "0"]
+            # host-only worker: it must not contend for the chip the
+            # dispatcher's own process may hold
             w.proc = subprocess.Popen(cmd, stdout=subprocess.PIPE,
-                                      stderr=subprocess.DEVNULL, text=True)
+                                      stderr=subprocess.DEVNULL, text=True,
+                                      env=cpu_env())
             line = await asyncio.wait_for(
                 asyncio.to_thread(w.proc.stdout.readline), _SPAWN_TIMEOUT_S)
             if not line.startswith("PORT "):

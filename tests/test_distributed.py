@@ -6,9 +6,11 @@ Exercises on an 8-device (pod=2, data=2, model=2) host mesh:
   * expert-parallel MoE (shard_map all_to_all path) vs the local oracle.
 """
 
+import os
 import subprocess
 import sys
 import textwrap
+from pathlib import Path
 
 import pytest
 
@@ -24,8 +26,9 @@ _SCRIPT = textwrap.dedent("""
     from repro.models import init_params, init_cache, decode_step
     from repro.models.context import DistContext
     from repro.compression import split_runtime as SR
+    from repro.launch.mesh import make_mesh
 
-    mesh = jax.make_mesh((2, 2, 2), ("pod", "data", "model"))
+    mesh = make_mesh((2, 2, 2), ("pod", "data", "model"))
     cfg = dataclasses.replace(
         reduced(ARCHS["codeqwen1.5-7b"]), num_layers=4, vocab_size=64)
     params = init_params(cfg, jax.random.PRNGKey(0))
@@ -65,13 +68,13 @@ _SCRIPT = textwrap.dedent("""
 
 @pytest.mark.timeout(600)
 def test_split_runtime_and_ep_moe_multidevice():
-    import os
-    env = {"PYTHONPATH": "src", "PATH": "/usr/bin:/bin", "HOME": "/root",
+    env = {"PYTHONPATH": "src", "PATH": "/usr/bin:/bin",
+           "HOME": os.environ.get("HOME", ""),
            # force the host platform: without this, containers that ship
            # libtpu spend 60s+ probing for TPU metadata before falling back
            "JAX_PLATFORMS": os.environ.get("JAX_PLATFORMS", "cpu")}
     res = subprocess.run([sys.executable, "-c", _SCRIPT],
                          capture_output=True, text=True, timeout=600,
-                         env=env, cwd="/root/repo")
+                         env=env, cwd=Path(__file__).resolve().parents[1])
     assert "DISTRIBUTED_OK" in res.stdout, \
         f"stdout:\n{res.stdout[-2000:]}\nstderr:\n{res.stderr[-3000:]}"

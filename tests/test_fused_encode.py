@@ -64,6 +64,35 @@ class TestFusedPerTensor:
         np.testing.assert_array_equal(hj, hk)
 
 
+class TestMultiRowBlock:
+    """Views taller than one 256-row kernel block: the bit-pack works
+    per row block, so the unpack must find every block's slabs."""
+
+    @pytest.mark.parametrize("granularity", ["tensor", "channel"])
+    @pytest.mark.parametrize("n_levels", [2, 4, 16])
+    def test_kernel_matches_jnp(self, backends, granularity, n_levels):
+        rng = np.random.default_rng(n_levels)
+        if granularity == "tensor":
+            x = rng.normal(0, 2, (300_000,)).astype(np.float32)
+            spec = QuantSpec(-3.0, 3.0, n_levels)
+        else:
+            x = rng.normal(0, 2, (4, 8, 300)).astype(np.float32)
+            lo = np.full((300, 1), -3.0, np.float32) \
+                + rng.uniform(0, 1, (300, 1)).astype(np.float32)
+            plan = TilePlan(channel_axis=-1, channel_group_size=1,
+                            spatial_block_size=0, n_channels=300)
+            spec = QuantSpec(lo, lo + 5.0, n_levels, -1, None, plan)
+        jb, kb = backends
+        xj = jnp.asarray(x)
+        cj, hj = jb.encode_fused(xj, spec, _bits(n_levels), want_hist=True)
+        ck, hk = kb.encode_fused(xj, spec, _bits(n_levels), want_hist=True)
+        np.testing.assert_array_equal(cj, ck)
+        np.testing.assert_array_equal(hj, hk)
+        np.testing.assert_array_equal(
+            np.asarray(kb.coded_indices_device(xj, spec, _bits(n_levels))),
+            cj)
+
+
 class TestFusedTiled:
     @pytest.mark.parametrize("geom", [
         # (shape, group, spatial_block): non-multiple channel counts,
@@ -157,19 +186,23 @@ class TestFusedCodecStreams:
 
 
 class TestUnpackBytes:
+    @pytest.mark.parametrize("rows", [16, 512])
     @pytest.mark.parametrize("bits", [1, 2, 4, 3, 6, 8])
-    def test_pack_unpack_roundtrip_layout(self, bits):
+    def test_pack_unpack_roundtrip_layout(self, bits, rows):
+        """Megakernel byte layout: each row block of min(256, R) rows
+        splits into ``per`` row slabs, slab k at bit offset k * bits."""
         rng = np.random.default_rng(bits)
         per = 8 // bits if bits in (1, 2, 4) else 1
         vals = rng.integers(0, 1 << min(bits, 8),
-                            size=(4, 16 * per)).astype(np.int32)
+                            size=(rows, 128)).astype(np.int32)
         if per == 1:
             packed = vals.astype(np.uint8)
         else:
-            shifts = np.arange(per, dtype=np.uint8) * bits
-            packed = np.sum(
-                vals.reshape(4, -1, per).astype(np.uint8) << shifts,
-                axis=-1).astype(np.uint8)
+            br = min(256, rows)
+            slabs = vals.reshape(rows // br, per, br // per, 128)
+            shifts = (np.arange(per, dtype=np.uint8) * bits)[:, None, None]
+            packed = np.sum(slabs.astype(np.uint8) << shifts, axis=1,
+                            dtype=np.uint8).reshape(rows // per, 128)
         np.testing.assert_array_equal(ops.unpack_bytes(packed, bits), vals)
 
 
