@@ -1,0 +1,12 @@
+"""Device: share of the traced window in which no operation ran on the
+chip (1 - union of device-op intervals / window)."""
+
+UNIT = "%"
+SOURCE = "device_trace"
+
+
+def read(run):
+    t = run["trace"]
+    if not t or not t["window_s"]:
+        return None
+    return 100.0 * (1.0 - t["busy_s"] / t["window_s"])
