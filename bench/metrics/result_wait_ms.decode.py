@@ -1,0 +1,12 @@
+"""Boundary round trip, after the send (``transport/client.py``): mean
+time per decode crossing in the ``result_wait`` span, from the last
+frame written to the cloud's RESULT unpacked (cloud decode and echo)."""
+
+from benchkit import stages
+
+UNIT = "ms"
+SOURCE = "program_span"
+
+
+def read(run):
+    return stages.stage_ms(run, "decode", "result_wait")

@@ -356,18 +356,12 @@ class JnpBackend:
             if want_hist:
                 raise ValueError("emit_wire returns wire bytes; per-tile "
                                  "histograms need the index path")
-            with tr.span("fused_launch", backend=self.name), \
-                    tr.annotate("repro.encode_fused"):
+            with tr.span("fused_launch", backend=self.name):
                 coded = self.coded_indices_device(x, spec, bits)
             return _encode_wire(coded, spec, chunk_bounds,
                                 use_kernel=False, interpret=False), None
-        with tr.span("fused_launch", backend=self.name), \
-                tr.annotate("repro.encode_fused"):
+        with tr.span("fused_launch", backend=self.name):
             q = self.quantize(x, spec)
-            if tr.enabled:
-                # bound the launch span at the device sync, so the
-                # device_to_host span measures only the transfer
-                q = jax.block_until_ready(q)
         with tr.span("device_to_host"):
             q = np.asarray(q)
         with tr.span("host_unpack"):
@@ -522,8 +516,7 @@ class KernelBackend:
             if want_hist:
                 raise ValueError("emit_wire returns wire bytes; per-tile "
                                  "histograms need the index path")
-            with tr.span("fused_launch", backend=self.name), \
-                    tr.annotate("repro.encode_fused"):
+            with tr.span("fused_launch", backend=self.name):
                 coded = self.coded_indices_device(x, spec, bits)
             return _encode_wire(coded, spec, chunk_bounds,
                                 use_kernel=True,
@@ -531,19 +524,15 @@ class KernelBackend:
         if spec.ecsq is not None or spec.n_levels > HIST_WIDTH:
             # no fused kernel for designed quantizers / wide histograms:
             # kernel-quantize, then the host fallback of the contract
-            with tr.span("fused_launch", backend=self.name), \
-                    tr.annotate("repro.encode_fused"):
+            with tr.span("fused_launch", backend=self.name):
                 q = self.quantize(x, spec)
-                if tr.enabled:
-                    q = jax.block_until_ready(q)
             with tr.span("device_to_host"):
                 q = np.asarray(q)
             with tr.span("host_unpack"):
                 coded = _coded_order(q, spec)
             return coded, (_tile_hists_np(coded, spec) if want_hist
                            else None)
-        with tr.span("fused_launch", backend=self.name), \
-                tr.annotate("repro.encode_fused"):
+        with tr.span("fused_launch", backend=self.name):
             if spec.plan is None:
                 packed, hist, lay = ops.encode_fused(
                     x, float(spec.cmin), float(spec.cmax),
@@ -559,11 +548,6 @@ class KernelBackend:
                 packed, hist, lay = ops.encode_fused(
                     x, lo, hi, n_levels=spec.n_levels, bits=bits,
                     plan=plan, interpret=self.interpret)
-            if tr.enabled:
-                # bound the launch at the device sync so the transfer
-                # span below measures only the packed-bytes fetch (the
-                # path's single device->host transfer)
-                packed = jax.block_until_ready(packed)
         with tr.span("device_to_host"):
             packed = np.asarray(packed)
             hist = np.asarray(hist) if want_hist else hist

@@ -2,7 +2,7 @@
 
 Runs the continuous-batching engine on a (reduced by default) config, with
 the paper's codec applied at the split boundary, and prints tokens/s, the
-measured split-link rate, and per-request latency.
+measured split-link rate, and each request's time to first token.
 
 The codec is calibrated from a *warm-up batch of real split-layer
 activations* (``--clip-mode model|empirical|minmax|aciq``, the paper's
@@ -353,11 +353,11 @@ def serve(cfg, args, params=None) -> dict:
     if eng.rate_log:
         print(f"split-link rate: {np.mean(eng.rate_log):.3f} bits/element "
               f"({16 / max(np.mean(eng.rate_log), 1e-9):.1f}x vs bf16)")
-    if eng.latency_log:
-        lat = [d["latency_s"] for d in eng.latency_log]
-        print(f"request latency: mean={np.mean(lat):.3f}s "
-              f"p50={np.percentile(lat, 50):.3f}s "
-              f"max={np.max(lat):.3f}s")
+    ttft = [r.ttft_s for r in reqs if r.ttft_s is not None]
+    if ttft:
+        print(f"time to first token: mean={np.mean(ttft):.3f}s "
+              f"p50={np.percentile(ttft, 50):.3f}s "
+              f"max={np.max(ttft):.3f}s")
     ec = eng.counters
     print(f"engine: {ec['steps']} steps, occupancy "
           f"{ec['batch_occupancy_avg']:.2f}, {ec['refills']} refills, "

@@ -18,14 +18,19 @@ to ~0%).  When enabled, each closed span
 - feeds ``repro_pipeline_stage_latency_seconds{stage=...}`` in the
   default metrics registry.
 
+With ``jax_trace`` on, every span is also a
+``jax.profiler.TraceAnnotation`` named ``repro.<stage>`` (the megakernel
+dispatch, stage ``fused_launch``, keeps the name ``repro.encode_fused``),
+so the spans sit on the host plane of a profiler trace, on the device
+trace's clock.  Spans never wait for the device: a stage around a jitted
+call times its dispatch, and the device's own time comes from the trace.
+
 ``REPRO_OBS_TRACE=1`` enables tracing at import; ``REPRO_OBS_JAX_TRACE=1``
-additionally wraps the fused-encode megakernel dispatch in
-``jax.profiler.TraceAnnotation`` so spans line up with XLA traces.
+also turns on the profiler annotations.
 """
 
 from __future__ import annotations
 
-import contextlib
 import contextvars
 import itertools
 import json
@@ -39,6 +44,9 @@ from .metrics import default_registry
 __all__ = ["Span", "Tracer", "configure_tracing", "span", "tracer"]
 
 _STAGE_HIST = "repro_pipeline_stage_latency_seconds"
+
+# profiler annotation names that differ from ``repro.<stage>``
+_ANNOTATION_NAMES = {"fused_launch": "repro.encode_fused"}
 
 _current_span: contextvars.ContextVar = contextvars.ContextVar(
     "repro_obs_current_span", default=None)
@@ -64,7 +72,7 @@ _NULL_SPAN = _NullSpan()
 
 class Span:
     __slots__ = ("stage", "attrs", "span_id", "parent_id", "t_start",
-                 "dur_s", "_tracer", "_token", "_t0")
+                 "dur_s", "_tracer", "_token", "_t0", "_annotation")
 
     def __init__(self, tracer: "Tracer", stage: str, attrs: dict):
         self.stage = stage
@@ -76,6 +84,7 @@ class Span:
         self._tracer = tracer
         self._token = None
         self._t0 = 0.0
+        self._annotation = None
 
     def set(self, **attrs):
         self.attrs.update(attrs)
@@ -85,12 +94,19 @@ class Span:
         parent = _current_span.get()
         self.parent_id = parent.span_id if parent is not None else None
         self._token = _current_span.set(self)
+        if self._tracer.jax_trace:
+            from jax.profiler import TraceAnnotation
+            self._annotation = TraceAnnotation(_ANNOTATION_NAMES.get(
+                self.stage, "repro." + self.stage))
+            self._annotation.__enter__()
         self.t_start = time.time()
         self._t0 = time.perf_counter()
         return self
 
     def __exit__(self, exc_type, exc, tb):
         self.dur_s = time.perf_counter() - self._t0
+        if self._annotation is not None:
+            self._annotation.__exit__(exc_type, exc, tb)
         if self._token is not None:
             _current_span.reset(self._token)
         if exc_type is not None:
@@ -154,17 +170,6 @@ class Tracer:
                     self._event_path = None  # stop retrying a dead path
         if self._hist is not None:
             self._hist.observe(sp.dur_s, stage=sp.stage)
-
-    # jax.profiler hook ------------------------------------------------
-    def annotate(self, name: str):
-        """TraceAnnotation ctx for the megakernel dispatch (opt-in)."""
-        if not (self.enabled and self.jax_trace):
-            return contextlib.nullcontext()
-        try:
-            from jax.profiler import TraceAnnotation
-        except Exception:
-            return contextlib.nullcontext()
-        return TraceAnnotation(name)
 
     # analysis helpers -------------------------------------------------
     def snapshot_events(self) -> list[dict]:

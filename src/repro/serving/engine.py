@@ -12,13 +12,22 @@ is idle the engine starts a fresh epoch with a full-batch prefill (which
 also admits prompts longer than the current position).
 
 The codec path reports bits/element of the split-layer transfer per step,
-and per-request wall-clock latency lands in ``latency_log``.
+and each request's first token is stamped (``Request.t_first``) and feeds
+the ``repro_engine_ttft_seconds`` histogram.
+
+With a host codec (``codec_host_fn``) every boundary crossing is traced
+as three spans sharing a ``crossing`` number -- ``split_head`` (head
+dispatch and the boundary fetch), ``split_roundtrip`` (the host codec
+call) and ``split_tail`` (the reconstruction's transfer and the tail
+dispatch) -- and every step's host read of the sampled tokens as
+``token_read``; each carries ``kind`` (``prefill``/``decode``).
 """
 
 from __future__ import annotations
 
 import collections
 import dataclasses
+import itertools
 import logging
 import time
 from typing import Callable
@@ -45,7 +54,14 @@ class Request:
     out_tokens: list = dataclasses.field(default_factory=list)
     done: bool = False
     t_admit: float | None = None
+    t_first: float | None = None    # first generated token appended
     t_done: float | None = None
+
+    @property
+    def ttft_s(self) -> float | None:
+        if self.t_admit is None or self.t_first is None:
+            return None
+        return self.t_first - self.t_admit
 
     @property
     def latency_s(self) -> float | None:
@@ -54,13 +70,18 @@ class Request:
         return self.t_done - self.t_admit
 
 
+def _named_jit(name: str, fn):
+    """``jax.jit(fn)`` whose program is named ``jit_<name>``."""
+    fn.__name__ = fn.__qualname__ = name
+    return jax.jit(fn)
+
+
 class ServeEngine:
     def __init__(self, cfg: ModelConfig, params, *, slots: int = 4,
                  max_seq: int = 256, ctx=None, codec_fn=None,
                  codec: FeatureCodec | None = None, codec_host_fn=None,
                  refill_align: int = 1,
-                 metrics: MetricsRegistry | None = None,
-                 latency_log_size: int = 4096):
+                 metrics: MetricsRegistry | None = None):
         """``codec`` is the preferred split-layer hookup: a calibrated
         :class:`FeatureCodec` (any granularity/backend) whose fused
         fake-quant + rate estimate is applied at the boundary.  The raw
@@ -87,10 +108,7 @@ class ServeEngine:
 
         ``metrics``: a :class:`MetricsRegistry` to register this engine's
         instruments in (fresh per engine by default, so tests and
-        co-hosted engines never share series).  ``latency_log_size``
-        bounds the per-request ``latency_log`` ring buffer -- a
-        long-lived serving process keeps the recent window (p50/p99 are
-        exposed via the registry), not an unbounded list."""
+        co-hosted engines never share series)."""
         self.cfg, self.params, self.ctx = cfg, params, ctx
         if sum(x is not None for x in (codec, codec_fn, codec_host_fn)) > 1:
             raise ValueError("pass at most one of codec, codec_fn, "
@@ -104,8 +122,7 @@ class ServeEngine:
         self.refill_align = max(1, refill_align)
         self._finite = True
         self.rate_log: collections.deque = collections.deque(maxlen=1 << 16)
-        self.latency_log: collections.deque = collections.deque(
-            maxlen=max(1, latency_log_size))
+        self._crossings = itertools.count()
         self.metrics = metrics if metrics is not None else MetricsRegistry()
         m = self.metrics
         self._m = {
@@ -125,55 +142,70 @@ class ServeEngine:
         }
         self._m_requests = m.counter("repro_engine_requests_total",
                                      "requests retired")
-        self._m_latency = m.histogram(
-            "repro_engine_request_latency_seconds",
-            "request wall-clock latency (admit -> retire)")
-        self._m_lat_p50 = m.gauge(
-            "repro_engine_request_latency_p50_seconds",
-            "p50 latency over the latency_log ring buffer")
-        self._m_lat_p99 = m.gauge(
-            "repro_engine_request_latency_p99_seconds",
-            "p99 latency over the latency_log ring buffer")
+        self._m_ttft = m.histogram(
+            "repro_engine_ttft_seconds",
+            "time to first token (admit -> first token appended)")
         self._m_bpe = m.histogram(
             "repro_engine_split_rate_bpe",
             "split-layer coded bits/element per decode step",
             buckets=BPE_BUCKETS)
 
+        # each program is named after the model function it runs
+        # (``jit_decode_to_boundary``, ...), so profiles and compiled
+        # modules tell the halves apart
         if codec_host_fn is not None:
-            self._prefill_pre = jax.jit(
+            self._prefill_pre = _named_jit(
+                "prefill_to_boundary",
                 lambda p, t, c: prefill_to_boundary(cfg, p, t, c, ctx=ctx))
-            self._prefill_post = jax.jit(
+            self._prefill_post = _named_jit(
+                "prefill_from_boundary",
                 lambda p, x, c: prefill_from_boundary(cfg, p, x, c, ctx=ctx))
-            self._decode_pre = jax.jit(
+            self._decode_pre = _named_jit(
+                "decode_to_boundary",
                 lambda p, t, c, pos: decode_to_boundary(cfg, p, t, c, pos,
                                                         ctx=ctx))
-            self._decode_post = jax.jit(
+            self._decode_post = _named_jit(
+                "decode_from_boundary",
                 lambda p, x, c, pos: decode_from_boundary(cfg, p, x, c, pos,
                                                           ctx=ctx))
             self._prefill = self._split_prefill
             self._decode = self._split_decode
         else:
-            self._prefill = jax.jit(
+            self._prefill = _named_jit(
+                "prefill",
                 lambda p, t, c: prefill(cfg, p, t, c, ctx=ctx,
                                         codec_fn=codec_fn))
-            self._decode = jax.jit(
+            self._decode = _named_jit(
+                "decode_step",
                 lambda p, t, c, pos: decode_step(cfg, p, t, c, pos, ctx=ctx,
                                                  codec_fn=codec_fn))
+
+    def _cross(self, kind, head, tail):
+        """One boundary crossing: ``head()`` -> boundary tensor, the host
+        codec round trip, ``tail(recon)``."""
+        n = next(self._crossings)
+        with span("split_head", kind=kind, crossing=n):
+            x, pre = head()
+            x = np.asarray(x, np.float32)
+        with span("split_roundtrip", kind=kind, crossing=n):
+            recon, rate = self.codec_host_fn(x)
+        with span("split_tail", kind=kind, crossing=n):
+            logits, post = tail(jnp.asarray(recon))
+        return logits, list(pre) + list(post), rate
 
     def _split_prefill(self, p, toks, cache):
         """Prefill as two jitted halves with the host codec round-trip
         run eagerly in between (``codec_host_fn`` mode)."""
-        x, pre = self._prefill_pre(p, toks, cache)
-        recon, _ = self.codec_host_fn(np.asarray(x, np.float32))
-        logits, post = self._prefill_post(p, jnp.asarray(recon), cache)
-        return logits, list(pre) + list(post)
+        logits, cache, _ = self._cross(
+            "prefill", lambda: self._prefill_pre(p, toks, cache),
+            lambda recon: self._prefill_post(p, recon, cache))
+        return logits, cache
 
     def _split_decode(self, p, cur, cache, pos):
-        x, pre = self._decode_pre(p, cur, cache, pos)
-        recon, rate = self.codec_host_fn(np.asarray(x, np.float32))
-        logits, post = self._decode_post(p, jnp.asarray(recon), cache, pos)
-        return logits, list(pre) + list(post), \
-            {"codec_rate_bits": np.float32(rate)}
+        logits, cache, rate = self._cross(
+            "decode", lambda: self._decode_pre(p, cur, cache, pos),
+            lambda recon: self._decode_post(p, recon, cache, pos))
+        return logits, cache, {"codec_rate_bits": np.float32(rate)}
 
     # -- scheduling -----------------------------------------------------------
 
@@ -190,21 +222,24 @@ class ServeEngine:
         active: list[Request | None] = [None] * self.slots
         cache = None
         cur = None          # (slots,) next token per slot
+        cur_kind = None     # the step that sampled ``cur``
         pos = 0             # shared absolute position of the next decode
 
         while queue or any(r is not None for r in active):
             if all(r is None for r in active):
                 cache, cur, pos = self._start_epoch(queue, active)
+                cur_kind = "prefill"
                 continue
             # one decode step for every slot (finished/empty slots ride
             # along; their logits are ignored)
-            for i, r in enumerate(active):
-                if r is None:
-                    continue
-                if len(r.out_tokens) < r.max_new_tokens:
-                    r.out_tokens.append(int(cur[i]))
-                if len(r.out_tokens) >= r.max_new_tokens:
-                    self._retire(active, i)
+            with span("token_read", kind=cur_kind):
+                for i, r in enumerate(active):
+                    if r is None:
+                        continue
+                    if len(r.out_tokens) < r.max_new_tokens:
+                        self._append_token(r, int(cur[i]))
+                    if len(r.out_tokens) >= r.max_new_tokens:
+                        self._retire(active, i)
             if all(r is None for r in active) and not queue:
                 break
             if pos % self.refill_align == 0:
@@ -226,8 +261,15 @@ class ServeEngine:
                 self._m_bpe.observe(bpe)
             self._finite &= jnp.all(jnp.isfinite(lg))
             cur = jnp.argmax(lg, axis=-1).astype(jnp.int32)
+            cur_kind = "decode"
             pos += 1
         return requests
+
+    def _append_token(self, r: Request, token: int) -> None:
+        r.out_tokens.append(token)
+        if r.t_first is None:
+            r.t_first = time.perf_counter()
+            self._m_ttft.observe(r.t_first - r.t_admit)
 
     @property
     def logits_finite(self) -> bool:
@@ -239,15 +281,7 @@ class ServeEngine:
         r = active[i]
         r.done = True
         r.t_done = time.perf_counter()
-        self.latency_log.append({
-            "slot": i, "prompt_len": int(len(r.prompt)),
-            "new_tokens": len(r.out_tokens), "latency_s": r.latency_s,
-        })
         self._m_requests.inc()
-        self._m_latency.observe(r.latency_s)
-        lat = [d["latency_s"] for d in self.latency_log]
-        self._m_lat_p50.set(float(np.percentile(lat, 50)))
-        self._m_lat_p99.set(float(np.percentile(lat, 99)))
         log.info("request done: slot=%d prompt_len=%d tokens=%d "
                  "latency=%.3fs", i, len(r.prompt), len(r.out_tokens),
                  r.latency_s)
@@ -261,10 +295,10 @@ class ServeEngine:
     @property
     def counters(self) -> dict:
         """Structured serving metrics (the observability satellite):
-        slot occupancy of the continuous batch, admission churn, the
-        split-layer rate actually spent, and request-latency percentiles
-        over the ``latency_log`` window.  The same numbers live as
-        ``repro_engine_*`` instruments in :attr:`metrics`."""
+        slot occupancy of the continuous batch, admission churn and the
+        split-layer rate actually spent.  The same numbers live as
+        ``repro_engine_*`` instruments in :attr:`metrics`, beside the
+        time-to-first-token histogram."""
         t = {k: int(c.value()) for k, c in self._m.items()}
         return {
             **t,
@@ -273,8 +307,6 @@ class ServeEngine:
             "split_bpe_avg": (float(np.mean(self.rate_log))
                               if self.rate_log else 0.0),
             "requests_done": int(self._m_requests.value()),
-            "request_latency_p50_s": self._m_lat_p50.value(),
-            "request_latency_p99_s": self._m_lat_p99.value(),
         }
 
     def _start_epoch(self, queue: list, active: list):
@@ -345,7 +377,8 @@ class ServeEngine:
         # request's first generated token is recorded here (it is fed to
         # the model at this iteration's decode); the next append phase
         # then records token two
-        r.out_tokens.append(int(first))
+        with span("token_read", kind="prefill"):
+            self._append_token(r, int(first))
         if len(r.out_tokens) >= r.max_new_tokens:
             self._retire(active, slot)
         return cache, cur

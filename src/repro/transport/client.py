@@ -477,6 +477,40 @@ class EdgeClient:
                 if not sent.done():
                     sent.set_result(sum(len(f) for f in frames))
 
+    async def _send(self, codec: FeatureCodec, x: np.ndarray,
+                    session: int, attempt: int) -> int:
+        """Encode ``x`` and write its frames; returns the wire bytes."""
+        if self.tick is not None:
+            return await self._submit_tick(codec, x, session)
+        coded = 0
+        acked = self._acked.get(session, ()) if attempt else ()
+        gen = tensor_to_frames(codec, x, session,
+                               chunk_elems=self.chunk_elems,
+                               coder_mode=self.coder_mode)
+        seq = 0
+        while True:
+            # chunk entropy-coding runs off-loop, overlapping the socket
+            frame_bytes = await asyncio.to_thread(next, gen, None)
+            if frame_bytes is None:
+                break
+            coded += len(frame_bytes)
+            if seq in acked:
+                # server already holds this frame from before the
+                # reconnect: replay skips it (still costs the encode,
+                # which keeps the byte accounting identical)
+                self._m_skipped.inc()
+                seq += 1
+                continue
+            seq += 1
+            async with self._write_lock:
+                with span("socket_write", session=str(session)):
+                    self._writer.write(frame_bytes)
+                    await self._writer.drain()
+            if self.rate_controller is not None:
+                buf = self._writer.transport.get_write_buffer_size()
+                self.rate_controller.on_queue_depth(buf // (1 << 16))
+        return coded
+
     async def submit(self, x: np.ndarray,
                      codec: FeatureCodec | None = None,
                      deadline_s: float | None = None) -> SubmitResult:
@@ -561,40 +595,11 @@ class EdgeClient:
                            attempt: int) -> SubmitResult:
         fut = asyncio.get_running_loop().create_future()
         self._pending[session] = fut
-        if self.tick is not None:
-            coded = await self._submit_tick(codec, x, session)
-        else:
-            coded = 0
-            acked = self._acked.get(session, ()) if attempt else ()
-            gen = tensor_to_frames(codec, x, session,
-                                   chunk_elems=self.chunk_elems,
-                                   coder_mode=self.coder_mode)
-            seq = 0
-            while True:
-                # chunk entropy-coding runs off-loop, overlapping the
-                # socket
-                frame_bytes = await asyncio.to_thread(next, gen, None)
-                if frame_bytes is None:
-                    break
-                coded += len(frame_bytes)
-                if seq in acked:
-                    # server already holds this frame from before the
-                    # reconnect: replay skips it (still costs the encode,
-                    # which keeps the byte accounting identical)
-                    self._m_skipped.inc()
-                    seq += 1
-                    continue
-                seq += 1
-                async with self._write_lock:
-                    with span("socket_write", session=str(session)):
-                        self._writer.write(frame_bytes)
-                        await self._writer.drain()
-                if self.rate_controller is not None:
-                    buf = self._writer.transport.get_write_buffer_size()
-                    self.rate_controller.on_queue_depth(buf // (1 << 16))
+        with span("edge_send", session=str(session)):
+            coded = await self._send(codec, x, session, attempt)
         send_s = time.perf_counter() - t0
-
-        arrays = await fut
+        with span("result_wait", session=str(session)):
+            arrays = await fut
         total_s = time.perf_counter() - t0
         self._m_submit.observe(total_s)
         fb = self._feedback.pop(session, None)
@@ -625,6 +630,9 @@ class SyncEdgeClient:
         self._run(self._client.connect())
 
     def _run(self, coro):
+        # the loop starts ``coro`` in a copy of the calling thread's
+        # context (``call_soon_threadsafe`` captures it), so the client's
+        # spans parent under the caller's open span
         return asyncio.run_coroutine_threadsafe(coro, self._loop).result()
 
     def submit(self, x: np.ndarray,

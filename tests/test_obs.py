@@ -403,31 +403,206 @@ class TestClientTelemetry:
                        "repro_client_submit_latency_seconds")["count"] == 2
 
 
+def _tiny_cfg():
+    from repro.configs import ARCHS, reduced
+    return dataclasses.replace(reduced(ARCHS["codeqwen1.5-7b"]),
+                               vocab_size=128, d_model=32, d_ff=64,
+                               num_heads=2, num_kv_heads=2, head_dim=16)
+
+
 class TestEngineTelemetry:
-    def test_latency_ring_and_percentiles(self):
+    def test_ttft_stamped_on_epoch_and_refill_paths(self):
         import jax
 
-        from repro.configs import ARCHS, reduced
         from repro.models import init_params
         from repro.serving import Request, ServeEngine
-        cfg = dataclasses.replace(reduced(ARCHS["codeqwen1.5-7b"]),
-                                  vocab_size=128, d_model=32, d_ff=64,
-                                  num_heads=2, num_kv_heads=2, head_dim=16)
+        cfg = _tiny_cfg()
         params = init_params(cfg, jax.random.PRNGKey(0))
-        eng = ServeEngine(cfg, params, slots=2, max_seq=64,
-                          latency_log_size=3)
+        eng = ServeEngine(cfg, params, slots=2, max_seq=64)
         rng = np.random.default_rng(0)
         reqs = [Request(prompt=rng.integers(0, 128, size=4)
-                        .astype(np.int32), max_new_tokens=2)
-                for _ in range(5)]
+                        .astype(np.int32), max_new_tokens=m)
+                for m in (2, 5, 3, 2, 4)]
         eng.generate(reqs)
         assert all(r.done for r in reqs)
-        # ring buffer: bounded at latency_log_size, not len(reqs)
-        assert len(eng.latency_log) == 3
         c = eng.counters
+        assert c["epochs"] >= 1 and c["refills"] >= 1
         assert c["requests_done"] == 5
-        assert c["request_latency_p99_s"] >= c["request_latency_p50_s"] > 0
+        assert not any("latency" in k for k in c)
+        epoch_admit = reqs[0].t_admit
+        refilled = [r for r in reqs if r.t_admit != epoch_admit]
+        assert refilled, "no request came in through a refill"
+        for r in reqs:
+            assert r.t_admit <= r.t_first <= r.t_done
+            assert r.ttft_s == r.t_first - r.t_admit
         snap = eng.metrics.snapshot()
         assert _series(snap, "repro_engine_requests_total")["value"] == 5
-        assert _series(
-            snap, "repro_engine_request_latency_seconds")["count"] == 5
+        assert _series(snap, "repro_engine_ttft_seconds")["count"] == 5
+        assert not any("request_latency" in name for name in snap)
+
+
+@pytest.fixture(scope="module")
+def loopback_engine():
+    """A smoke-width split engine whose boundary round trip runs through
+    ``serve.py``'s loopback transport (edge client, socket, cloud server
+    thread)."""
+    import jax
+
+    from repro.launch.serve import _loopback_codec_fn
+    from repro.models import init_params
+    from repro.serving import ServeEngine
+    cfg = _tiny_cfg()
+    params = init_params(cfg, jax.random.PRNGKey(0))
+    rng = np.random.default_rng(3)
+    codec = calibrate(CodecConfig(n_levels=4, clip_mode="minmax",
+                                  constrain_cmin_zero=False),
+                      samples=rng.normal(0, 1, 4096).astype(np.float32))
+    host_fn, cleanup = _loopback_codec_fn(codec, 1 << 16)
+    eng = ServeEngine(cfg, params, slots=2, max_seq=32,
+                      codec_host_fn=host_fn)
+    yield eng
+    cleanup()
+
+
+def _serve(eng, n_new=4):
+    from repro.serving import Request
+    rng = np.random.default_rng(5)
+    reqs = [Request(prompt=rng.integers(0, 128, 6).astype(np.int32),
+                    max_new_tokens=n_new) for _ in range(2)]
+    eng.generate(reqs)
+    assert all(r.done for r in reqs)
+    return reqs
+
+
+def _traced(eng, n_new=4, jax_trace=False):
+    _serve(eng, n_new)                  # compile outside the traced run
+    steps0 = eng.counters["steps"]
+    configure_tracing(enabled=True, jax_trace=jax_trace)
+    try:
+        tracer().reset()
+        _serve(eng, n_new)
+        return tracer().snapshot_events(), eng.counters["steps"] - steps0
+    finally:
+        configure_tracing(enabled=False, jax_trace=False)
+
+
+class TestServedStepTracing:
+    STAGES = ("split_head", "split_roundtrip", "split_tail")
+
+    def test_stage_spans_of_each_decode_step(self, loopback_engine):
+        events, steps = _traced(loopback_engine)
+        assert steps == 3
+        by_id = {e["span_id"]: e for e in events}
+        dec = [e for e in events if e.get("kind") == "decode"]
+        for stage in self.STAGES + ("token_read",):
+            assert sum(e["stage"] == stage for e in dec) == steps, stage
+        # the three stages of one crossing share its number, in order
+        for n in {e["crossing"] for e in dec if "crossing" in e}:
+            trio = sorted((e for e in events if e.get("crossing") == n),
+                          key=lambda e: e["t_start"])
+            assert [e["stage"] for e in trio] == list(self.STAGES)
+            assert len({e["kind"] for e in trio}) == 1
+        # the edge client's spans parent under the engine's round trip
+        # and carry the session id the cloud's spans carry
+        rts = {e["span_id"] for e in events
+               if e["stage"] == "split_roundtrip"}
+        edge = [e for e in events
+                if e["stage"] in ("edge_send", "result_wait")]
+        assert len(edge) == 2 * len(rts)
+        assert {e["parent_id"] for e in edge} == rts
+        sessions = {e["session"] for e in edge}
+        cloud = {e["session"].split(":")[1] for e in events
+                 if e["stage"] == "socket_write" and ":" in e["session"]}
+        assert sessions == cloud and len(sessions) == len(rts)
+        for e in edge:
+            rt = by_id[e["parent_id"]]
+            assert rt["t_start"] <= e["t_start"]
+            assert e["t_start"] + e["dur_s"] <= rt["t_start"] + rt["dur_s"]
+
+    def test_stage_spans_cover_the_decode_steps(self, loopback_engine):
+        """From each decode step's head to the read of its tokens, the
+        stage spans leave under a tenth of the wall time untraced."""
+        events, _ = _traced(loopback_engine)
+        dec = sorted((e for e in events if e.get("kind") == "decode"
+                      and e["stage"] in self.STAGES + ("token_read",)),
+                     key=lambda e: e["t_start"])
+        wall = covered = 0.0
+        for i in range(0, len(dec), 4):
+            step = dec[i:i + 4]
+            assert [e["stage"] for e in step] == list(self.STAGES) \
+                + ["token_read"]
+            end = step[-1]["t_start"] + step[-1]["dur_s"]
+            wall += end - step[0]["t_start"]
+            covered += sum(e["dur_s"] for e in step)
+        assert covered >= 0.9 * wall, (covered, wall)
+
+    def test_disabled_makes_no_span_and_no_annotation(self, loopback_engine,
+                                                      monkeypatch):
+        import jax.profiler
+
+        from repro.obs import tracing
+        _serve(loopback_engine)
+        made = []
+        monkeypatch.setattr(tracing, "Span",
+                            lambda *a, **k: made.append(a))
+        monkeypatch.setattr(jax.profiler, "TraceAnnotation",
+                            lambda *a, **k: made.append(a))
+        configure_tracing(enabled=False, jax_trace=False)
+        _serve(loopback_engine)
+        assert made == []
+
+    def test_spans_are_profiler_annotations(self, loopback_engine, tmp_path):
+        import glob
+
+        import jax
+        _serve(loopback_engine)
+        jax.profiler.start_trace(str(tmp_path))
+        try:
+            events, _ = _traced(loopback_engine, jax_trace=True)
+        finally:
+            jax.profiler.stop_trace()
+        assert events
+        path, = glob.glob(str(tmp_path / "**" / "*.xplane.pb"),
+                          recursive=True)
+        pd = jax.profiler.ProfileData.from_file(path)
+        names = {e.name for plane in pd.planes
+                 if plane.name.startswith("/host:CPU")
+                 for line in plane.lines for e in line.events}
+        for name in ("repro.split_roundtrip", "repro.encode_fused",
+                     "repro.edge_send", "repro.token_read"):
+            assert name in names, name
+
+    @pytest.mark.parametrize("attr,program", [
+        ("_prefill_pre", "jit_prefill_to_boundary"),
+        ("_prefill_post", "jit_prefill_from_boundary"),
+        ("_decode_pre", "jit_decode_to_boundary"),
+        ("_decode_post", "jit_decode_from_boundary"),
+        ("_prefill", "jit_prefill"),
+        ("_decode", "jit_decode_step"),
+    ])
+    def test_programs_are_named(self, loopback_engine, attr, program):
+        import jax
+        import jax.numpy as jnp
+
+        from repro.models import init_cache
+        from repro.serving import ServeEngine
+        eng = loopback_engine
+        if attr in ("_prefill", "_decode"):
+            eng = ServeEngine(eng.cfg, eng.params, slots=2, max_seq=32)
+        cache = init_cache(eng.cfg, batch=2, max_seq=32,
+                           split=eng.codec_host_fn is not None)
+        toks, pos = jnp.zeros((2, 6), jnp.int32), jnp.int32(6)
+        cur = jnp.zeros((2,), jnp.int32)
+        if attr == "_prefill_pre" or attr == "_prefill":
+            args = (eng.params, toks, cache)
+        elif attr == "_decode_pre" or attr == "_decode":
+            args = (eng.params, cur, cache, pos)
+        elif attr == "_prefill_post":
+            x, _ = jax.eval_shape(eng._prefill_pre, eng.params, toks, cache)
+            args = (eng.params, x, cache)
+        else:
+            x, _ = jax.eval_shape(eng._decode_pre, eng.params, cur, cache,
+                                  pos)
+            args = (eng.params, x, cache, pos)
+        text = getattr(eng, attr).lower(*args).compile().as_text()
+        assert text.startswith(f"HloModule {program},"), text[:80]

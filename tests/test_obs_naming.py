@@ -27,7 +27,7 @@ class TestValidateName:
     @pytest.mark.parametrize("name,kind", [
         ("repro_server_ticks_total", "counter"),
         ("repro_client_coded_bytes_total", "counter"),
-        ("repro_engine_request_latency_seconds", "histogram"),
+        ("repro_engine_ttft_seconds", "histogram"),
         ("repro_rate_target_bpe", "gauge"),
         ("repro_server_queue_depth_count", "gauge"),
         ("repro_pipeline_stage_latency_seconds", "histogram"),

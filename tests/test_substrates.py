@@ -197,8 +197,9 @@ class TestServing:
         for r in reqs:
             assert r.done and len(r.out_tokens) == r.max_new_tokens
             assert r.latency_s is not None and r.latency_s >= 0
-        assert len(eng.latency_log) == len(reqs)
-        assert all(d["latency_s"] >= 0 for d in eng.latency_log)
+            assert r.ttft_s is not None and 0 <= r.ttft_s <= r.latency_s
+        assert eng.metrics.get("repro_engine_ttft_seconds").count() \
+            == len(reqs)
 
     def test_refilled_request_keeps_first_token(self, tiny_cfg):
         """Regression: the refill path must record the prefill argmax as
