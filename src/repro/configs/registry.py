@@ -47,7 +47,7 @@ QWEN3_MOE_235B = ModelConfig(
 )
 
 DEEPSEEK_67B = ModelConfig(
-    name="deepseek-67b", family="dense",
+    name="deepseek-llm-67b", family="dense",
     num_layers=95, d_model=8192, num_heads=64, num_kv_heads=8, head_dim=128,
     d_ff=22016, vocab_size=102400,
     pattern=(_A(),),

@@ -412,6 +412,18 @@ def encode_indices(idx: np.ndarray, n_levels: int, mode: str = "auto") -> bytes:
     raise ValueError(f"unknown coder mode {mode!r}")
 
 
+def coder_attrs(blobs, sizes) -> dict:
+    """Trace attributes of one entropy-coding call over ``blobs`` (coded
+    payloads, each led by its coder-id byte) of ``sizes`` elements: the
+    element count, how many of them a rANS coder took (any coder but
+    serial CABAC's id 0), and ``coder`` -- ``serial`` or ``rans`` when all
+    went one way, else ``mixed``."""
+    n = int(sum(sizes))
+    r = int(sum(k for b, k in zip(blobs, sizes) if b[0] != _CODER_SERIAL))
+    coder = "serial" if r == 0 else "rans" if r == n else "mixed"
+    return {"n_elems": n, "rans_elems": r, "coder": coder}
+
+
 def _levels_list(n_levels, count: int) -> list[int]:
     """Normalize an ``n_levels`` argument (scalar or per-item sequence)."""
     if np.ndim(n_levels) == 0:

@@ -707,9 +707,10 @@ class FeatureCodec:
             return header + payload
         coded = self._fused_indices(x)[0] if fused \
             else self._coded_indices(x)
-        with span("entropy_encode", n_elems=int(coded.size)):
+        with span("entropy_encode") as sp:
             payload = cabac.encode_indices(coded, self.config.n_levels,
                                            mode=coder_mode)
+            sp.set(**cabac.coder_attrs([payload], [coded.size]))
         return header + payload
 
     def decode(self, data: bytes, shape=None) -> np.ndarray:
@@ -803,11 +804,11 @@ class FeatureCodec:
         batch = max(1, chunk_batch)
         for c0 in range(0, n_chunks, batch):
             ids = range(c0, min(c0 + batch, n_chunks))
-            with span("entropy_encode", chunks=len(ids)):
+            segs = [idx[c * chunk_elems:(c + 1) * chunk_elems] for c in ids]
+            with span("entropy_encode", chunks=len(ids)) as sp:
                 blobs = cabac.encode_indices_batch(
-                    [idx[c * chunk_elems:(c + 1) * chunk_elems]
-                     for c in ids],
-                    self.config.n_levels, mode=coder_mode)
+                    segs, self.config.n_levels, mode=coder_mode)
+                sp.set(**cabac.coder_attrs(blobs, [s.size for s in segs]))
             for c, blob in zip(ids, blobs):
                 yield struct.pack("<I", c) + blob
 

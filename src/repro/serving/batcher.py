@@ -324,8 +324,9 @@ def encode_tick(items, cfg: TickConfig = TickConfig()
             stats.elems += n
     if dev:
         from ..kernels import rans_coder
-        with span("entropy_encode",
-                  chunks=sum(len(b) for b in bounds_per)):
+        with span("entropy_encode", chunks=sum(len(b) for b in bounds_per),
+                  coder="rans", n_elems=stats.elems,
+                  rans_elems=stats.elems):
             pend = [rans_coder.dispatch_index_chunks(
                 coded[i], codec.config.n_levels, bounds_per[i],
                 use_kernel=codec.backend.name == "kernel",
@@ -335,9 +336,10 @@ def encode_tick(items, cfg: TickConfig = TickConfig()
                      for b in rans_coder.finalize_index_chunks(p)]
         seg_owner = [i for i, bl in enumerate(bounds_per) for _ in bl]
     else:
-        with span("entropy_encode", chunks=len(segments)):
+        with span("entropy_encode", chunks=len(segments)) as sp:
             blobs = cabac.encode_indices_batch(segments, seg_levels,
                                                mode=cfg.coder_mode)
+            sp.set(**cabac.coder_attrs(blobs, [s.size for s in segments]))
     stats.entropy_calls = 1
 
     with span("framing", sessions=len(items)):

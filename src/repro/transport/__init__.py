@@ -30,9 +30,10 @@ from .errors import (CODE_NAMES, E_BUSY, E_CORRUPT_STREAM, E_DEADLINE,
                      decode_error, encode_error)
 from .faultinject import ChaosReset, ChaosWriter, FaultPlan, wrap_writer
 from .framing import (FT_CHUNK, FT_END, FT_ERROR, FT_FEEDBACK, FT_HEADER,
-                      FT_HELLO, FT_METRICS, FT_PING, FT_RESULT, Frame,
-                      FrameReader, FramingError, encode_frame, pack_arrays,
-                      unpack_arrays)
+                      FT_HELLO, FT_METRICS, FT_PING, FT_RESULT,
+                      FT_RESULT_PART, Frame, FrameReader, FramingError,
+                      ResultAssembler, encode_frame, pack_arrays,
+                      result_frames, unpack_arrays)
 from .rate_control import (DEFAULT_LADDER, CodecBank, RateControlConfig,
                            RateController, Rung, as_rung, bank_cache_stats,
                            clear_bank_cache, rung_of_codec, shared_bank)
@@ -44,9 +45,9 @@ __all__ = [
     "EdgeClient", "SyncEdgeClient", "SubmitResult", "TransportError",
     "RetryPolicy",
     "Frame", "FrameReader", "FramingError", "encode_frame",
-    "pack_arrays", "unpack_arrays",
-    "FT_HEADER", "FT_CHUNK", "FT_END", "FT_RESULT", "FT_FEEDBACK",
-    "FT_ERROR", "FT_METRICS", "FT_HELLO", "FT_PING",
+    "pack_arrays", "unpack_arrays", "result_frames", "ResultAssembler",
+    "FT_HEADER", "FT_CHUNK", "FT_END", "FT_RESULT", "FT_RESULT_PART",
+    "FT_FEEDBACK", "FT_ERROR", "FT_METRICS", "FT_HELLO", "FT_PING",
     "E_UNSPECIFIED", "E_PROTOCOL", "E_CORRUPT_STREAM", "E_DECODE",
     "E_UNAUTHORIZED", "E_BUSY", "E_WORKER_RESTART", "E_SHUTDOWN",
     "E_DEADLINE", "RETRYABLE_CODES", "CODE_NAMES",

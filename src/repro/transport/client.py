@@ -54,7 +54,8 @@ from ..serving.batcher import TickConfig, encode_tick
 from .errors import E_DEADLINE, TransportError, decode_error
 from .faultinject import FaultPlan, wrap_writer
 from .framing import (FT_ERROR, FT_FEEDBACK, FT_HELLO, FT_METRICS,
-                      FT_RESULT, FrameReader, encode_frame, unpack_arrays)
+                      FT_RESULT, FT_RESULT_PART, FrameReader,
+                      ResultAssembler, encode_frame, unpack_arrays)
 from .rate_control import CodecBank, RateController, rung_of_codec
 from .stream_codec import (DEFAULT_CHUNK_ELEMS, Feedback, payloads_to_frames,
                            tensor_to_frames)
@@ -323,6 +324,7 @@ class EdgeClient:
 
     async def _read_loop(self) -> None:
         frames = FrameReader()
+        results = ResultAssembler()
         try:
             while True:
                 data = await self._reader.read(1 << 16)
@@ -330,10 +332,13 @@ class EdgeClient:
                     raise ConnectionError("cloud closed the connection")
                 frames.feed(data)
                 for frame in frames:
-                    if frame.ftype == FT_RESULT:
+                    if frame.ftype in (FT_RESULT, FT_RESULT_PART):
+                        payload = results.add(frame)
+                        if payload is None:
+                            continue
                         fut = self._pending.pop(frame.session, None)
                         if fut is not None and not fut.done():
-                            fut.set_result(unpack_arrays(frame.payload))
+                            fut.set_result(unpack_arrays(payload))
                     elif frame.ftype == FT_FEEDBACK:
                         fb = Feedback.decode(frame)
                         self._feedback[frame.session] = fb
@@ -354,6 +359,7 @@ class EdgeClient:
                                 json.loads(frame.payload.decode()))
                     elif frame.ftype == FT_ERROR:
                         err = decode_error(frame.payload)
+                        results.discard(frame.session)
                         fut = self._pending.pop(frame.session, None)
                         if fut is not None:
                             # session-scoped failure (shed, decode error):

@@ -13,7 +13,9 @@ Everything that crosses the edge<->cloud socket is a *frame*:
 * ``seq`` is a per-session counter used for diagnostics.
 * frame types: HEADER (stream meta + self-describing codec header),
   CHUNK (one entropy-coded chunk), END (end-of-tensor marker, payload =
-  ``<I`` chunk count), RESULT (cloud -> edge arrays), FEEDBACK
+  ``<I`` chunk count), RESULT (cloud -> edge arrays; a packed result
+  past ``MAX_PAYLOAD`` goes as RESULT_PART frames and a final RESULT,
+  see :func:`result_frames`), FEEDBACK
   (cloud -> edge link stats for the rate controller), ERROR (structured
   code + retryable flag + message, see :mod:`repro.transport.errors`;
   legacy bare utf-8 text still parses), METRICS (edge -> cloud: empty
@@ -40,6 +42,7 @@ VERSION = 1
 _FRAME_FMT = "<HBBIII"          # magic, ver, type, session, seq, length
 _FRAME_HEAD = struct.calcsize(_FRAME_FMT) + 4  # + crc32
 MAX_PAYLOAD = 1 << 26           # 64 MiB sanity bound per frame
+MAX_RESULT = 1 << 30            # 1 GiB bound on a reassembled RESULT
 
 FT_HEADER = 1
 FT_CHUNK = 2
@@ -55,6 +58,9 @@ FT_HELLO = 8
 # liveness probe: the receiver echoes the payload back in an FT_PING
 # frame (dispatcher <-> worker heartbeats)
 FT_PING = 9
+# a leading piece of a RESULT too large for one frame; the session's
+# final piece is its FT_RESULT frame (cloud -> edge)
+FT_RESULT_PART = 10
 
 
 class FramingError(ValueError):
@@ -128,6 +134,45 @@ class FrameReader:
             if frame is None:
                 return
             yield frame
+
+
+def result_frames(session: int, seq: int, payload: bytes) -> list[bytes]:
+    """A packed RESULT as wire frames.  Up to ``MAX_PAYLOAD`` it is one
+    RESULT frame; past it, RESULT_PART frames of ``MAX_PAYLOAD`` bytes
+    each and a final RESULT with the rest, at consecutive seqs.  The
+    receiver joins the parts in order (:class:`ResultAssembler`)."""
+    if len(payload) > MAX_RESULT:
+        raise FramingError(f"result too large: {len(payload)} bytes")
+    view = memoryview(payload)
+    cuts = range(0, max(len(payload), 1), MAX_PAYLOAD)
+    return [encode_frame(FT_RESULT if i == len(cuts) - 1 else FT_RESULT_PART,
+                         session, seq + i, view[a:a + MAX_PAYLOAD])
+            for i, a in enumerate(cuts)]
+
+
+class ResultAssembler:
+    """Joins each session's RESULT_PART frames and its final RESULT into
+    one payload, holding at most ``MAX_RESULT`` bytes per session."""
+
+    def __init__(self) -> None:
+        self._parts: dict[int, list[bytes]] = {}
+
+    def add(self, frame: Frame) -> bytes | None:
+        """Feed a RESULT_PART or RESULT frame; the whole payload once
+        the RESULT arrives, else None."""
+        parts = self._parts.setdefault(frame.session, [])
+        parts.append(frame.payload)
+        if sum(len(p) for p in parts) > MAX_RESULT:
+            del self._parts[frame.session]
+            raise FramingError(f"result of session {frame.session} exceeds "
+                               f"{MAX_RESULT} bytes")
+        if frame.ftype != FT_RESULT:
+            return None
+        del self._parts[frame.session]
+        return parts[0] if len(parts) == 1 else b"".join(parts)
+
+    def discard(self, session: int) -> None:
+        self._parts.pop(session, None)
 
 
 # -- small array (de)serializer for RESULT payloads --------------------------

@@ -2,7 +2,8 @@
 
 Accepts one or more edge connections, demultiplexes interleaved tensor
 sessions, and reconstructs split-layer tensors for the cloud half
-(``tail_fn``).  The result arrays go back in a RESULT frame; a FEEDBACK
+(``tail_fn``).  The result arrays go back in a RESULT frame (or, past
+the frame bound, RESULT_PART frames and a final RESULT); a FEEDBACK
 frame carries receiver-measured link throughput and queue depth for the
 edge-side rate controller.
 
@@ -67,8 +68,8 @@ from .errors import (E_BUSY, E_SHUTDOWN, E_UNAUTHORIZED, encode_error,
                      error_for_exception)
 from .faultinject import FaultPlan, wrap_writer
 from .framing import (FT_CHUNK, FT_END, FT_ERROR, FT_HEADER, FT_HELLO,
-                      FT_METRICS, FT_PING, FT_RESULT, FrameReader,
-                      FramingError, encode_frame, pack_arrays)
+                      FT_METRICS, FT_PING, FrameReader, FramingError,
+                      encode_frame, pack_arrays, result_frames)
 from .stream_codec import Feedback, TensorAssembler
 
 log = logging.getLogger(__name__)
@@ -256,6 +257,9 @@ class CloudServer:
         self._m_auth_fail = m.counter(
             "repro_server_auth_failures_total",
             "connections rejected at the HELLO auth check")
+        self._m_result_frames = m.counter(
+            "repro_server_result_frames_total",
+            "RESULT and RESULT_PART frames sent")
 
     def _sync_gauges(self) -> None:
         """Pull-style sources -> gauges (run per scrape / counters read)."""
@@ -650,21 +654,29 @@ class CloudServer:
             if not ready and not self._batcher.pending_sessions:
                 return
             with span("tick_drain", sessions=len(ready)):
-                # ONE batched entropy pass over every pending chunk of
-                # every session, across connections
-                failures = await asyncio.to_thread(self._batcher.drain)
-                for dec, exc in failures:
-                    await self._evict_decoder(dec, exc)
-                    kept = []
-                    for e in ready:
-                        if e[0].assembler.decoder is dec:
-                            self._m_pending.remove(session=e[0].obs_key)
-                            self._session_closed()
-                        else:
-                            kept.append(e)
-                    ready = kept
-                outs = await asyncio.to_thread(self._finish_ready,
-                                               [e[0] for e in ready])
+                try:
+                    # ONE batched entropy pass over every pending chunk of
+                    # every session, across connections
+                    failures = await asyncio.to_thread(self._batcher.drain)
+                    for dec, exc in failures:
+                        await self._evict_decoder(dec, exc)
+                        kept = []
+                        for e in ready:
+                            if e[0].assembler.decoder is dec:
+                                self._m_pending.remove(session=e[0].obs_key)
+                                self._session_closed()
+                            else:
+                                kept.append(e)
+                        ready = kept
+                    outs = await asyncio.to_thread(self._finish_ready,
+                                                   [e[0] for e in ready])
+                except Exception as e:              # noqa: BLE001
+                    # the tick itself failed (this may run in a timer's
+                    # task nobody awaits): every session in it is told
+                    log.error("tick drain failed: %s", e)
+                    for sess, *_ in ready:
+                        self._batcher.discard(sess.assembler.decoder)
+                    outs = [e] * len(ready)
                 self._m_ticks.inc()
                 self._m_tick_sessions.inc(len(ready))
                 for (sess, session_id, writer, sessions), out \
@@ -756,6 +768,9 @@ class CloudServer:
 
     async def _send_result(self, sess: _Session, session_id: int, writer,
                            sessions, arrays) -> None:
+        """FEEDBACK, then the packed arrays as RESULT frame(s).  A failure
+        to pack or write is answered with an FT_ERROR for the session, so
+        its submit raises instead of waiting."""
         elapsed = max(time.perf_counter() - sess.t_first, 1e-9)
         fb = Feedback(
             recv_bytes_per_s=sess.assembler.chunk_bytes / elapsed,
@@ -763,19 +778,27 @@ class CloudServer:
             queue_depth=len(sessions),
             active_sessions=len(sessions),
         )
-        # FEEDBACK goes out *before* RESULT: the client resolves the
-        # session on RESULT, so in-order delivery guarantees the submit
-        # sees its own link stats
-        try:
-            with span("socket_write", session=sess.obs_key):
-                writer.write(fb.encode(session_id, sess.seq))
-                writer.write(encode_frame(FT_RESULT, session_id,
-                                          sess.seq + 1,
-                                          pack_arrays([np.asarray(a)
-                                                       for a in arrays])))
-                await writer.drain()
-        except (ConnectionError, RuntimeError):
-            pass
+        with span("result_send", session=sess.obs_key) as sp:
+            try:
+                frames = result_frames(session_id, sess.seq + 1, pack_arrays(
+                    [np.asarray(a) for a in arrays]))
+                sp.set(frames=len(frames), bytes=sum(len(f) for f in frames))
+                self._m_result_frames.inc(len(frames))
+                # FEEDBACK goes out *before* RESULT: the client resolves
+                # the session on RESULT, so in-order delivery guarantees
+                # the submit sees its own link stats
+                try:
+                    with span("socket_write", session=sess.obs_key):
+                        writer.write(fb.encode(session_id, sess.seq))
+                        for frame in frames:
+                            writer.write(frame)
+                        await writer.drain()
+                except (ConnectionError, RuntimeError):
+                    return      # the connection is gone: nobody to tell
+            except Exception as e:                  # noqa: BLE001
+                self._m_errors.inc()
+                log.error("result for session %d failed: %s", session_id, e)
+                await self._send_error(writer, session_id, e)
 
     # -- disconnect: park (resumable) or forget --------------------------------
 

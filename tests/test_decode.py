@@ -71,7 +71,7 @@ def test_window_actually_limits_attention():
 
 def test_causality():
     """Changing a future token must not affect past logits (all archs)."""
-    for name in ("deepseek-67b", "rwkv6-3b", "recurrentgemma-2b", "gemma3-1b"):
+    for name in ("deepseek-llm-67b", "rwkv6-3b", "recurrentgemma-2b", "gemma3-1b"):
         cfg = reduced(ARCHS[name])
         params = init_params(cfg, jax.random.PRNGKey(0))
         tok = jax.random.randint(jax.random.PRNGKey(1), (1, 32), 0,

@@ -67,7 +67,7 @@ def test_param_count_formula_matches_init(name, rng):
 
 def test_full_config_param_counts_sane():
     """Full-size param counts should be in the ballpark the names imply."""
-    expect = {"dbrx-132b": (110e9, 150e9), "deepseek-67b": (60e9, 72e9),
+    expect = {"dbrx-132b": (110e9, 150e9), "deepseek-llm-67b": (60e9, 72e9),
               "qwen3-moe-235b-a22b": (200e9, 260e9), "gemma2-9b": (8e9, 12e9),
               "gemma3-1b": (0.7e9, 1.6e9), "codeqwen1.5-7b": (6e9, 9e9),
               "rwkv6-3b": (2e9, 4.5e9), "recurrentgemma-2b": (2e9, 3.6e9),

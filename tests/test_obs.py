@@ -163,6 +163,66 @@ class TestTracing:
         assert events[0]["error"] == "RuntimeError"
 
 
+class TestCoderAndResultSpans:
+    """The entropy coder each ``entropy_encode`` span's elements took, and
+    the cloud's ``result_send`` span with its frame count."""
+
+    @staticmethod
+    def _spans(fn):
+        configure_tracing(enabled=True)
+        try:
+            tracer().reset()
+            out = fn()
+            return out, tracer().snapshot_events()
+        finally:
+            configure_tracing(enabled=False)
+
+    @pytest.mark.parametrize("n,coder", [(8192, "serial"),
+                                         (1 << 16, "rans")])
+    def test_entropy_encode_names_its_coder(self, features, n, coder):
+        codec = _live_codec(features)
+        x = np.resize(features, (n // 16, 16))
+        _, events = self._spans(lambda: (
+            codec.encode(x), list(codec.encode_stream(x,
+                                                      chunk_elems=1 << 16))))
+        enc = [e for e in events if e["stage"] == "entropy_encode"]
+        assert len(enc) == 2        # the one-shot and the streamed encode
+        for e in enc:
+            assert (e["coder"], e["n_elems"]) == (coder, n)
+            assert e["rans_elems"] == (n if coder == "rans" else 0)
+
+    def test_mixed_batch_counts_its_rans_elements(self, features):
+        # per-tensor: chunk boundaries fall exactly every 2^16 elements
+        codec = calibrate(CodecConfig(n_levels=4, clip_mode="minmax",
+                                      constrain_cmin_zero=False),
+                          samples=features)
+        x = np.resize(features, ((1 << 16) // 16 + 512, 16))
+        _, events = self._spans(
+            lambda: list(codec.encode_stream(x, chunk_elems=1 << 16)))
+        (e,) = [e for e in events if e["stage"] == "entropy_encode"]
+        assert (e["coder"], e["n_elems"], e["rans_elems"]) == (
+            "mixed", x.size, 1 << 16)
+
+    def test_result_send_counts_its_frames(self, features, monkeypatch):
+        from repro.transport import framing
+        monkeypatch.setattr(framing, "MAX_PAYLOAD", 4096)
+        codec = _live_codec(features)
+
+        async def run():
+            async with CloudServer(echo_features=True) as srv:
+                async with EdgeClient("127.0.0.1", srv.port,
+                                      codec=codec) as client:
+                    await asyncio.wait_for(client.submit(features), 20)
+                return srv.metrics.get(
+                    "repro_server_result_frames_total").value()
+
+        frames, events = self._spans(lambda: asyncio.run(run()))
+        (send,) = [e for e in events if e["stage"] == "result_send"]
+        assert send["frames"] == frames == -(-send["bytes"] // 4096)
+        assert send["frames"] > 1
+        assert send["bytes"] > features.size * 4
+
+
 class TestExposition:
     def test_scrape_routes(self):
         reg = MetricsRegistry()

@@ -12,9 +12,10 @@ from repro.core import CodecConfig, calibrate
 from repro.core import cabac, rans
 from repro.transport import (CloudServer, CodecBank, EdgeClient, Frame,
                              FrameReader, FramingError, RateControlConfig,
-                             RateController, TensorAssembler, encode_frame,
-                             framing, pack_arrays, tensor_to_frames,
-                             unpack_arrays)
+                             RateController, ResultAssembler,
+                             TensorAssembler, TransportError, encode_frame,
+                             framing, pack_arrays, result_frames,
+                             tensor_to_frames, unpack_arrays)
 
 
 @pytest.fixture(scope="module")
@@ -322,6 +323,100 @@ class TestAsyncTransport:
         rc = asyncio.run(run())
         assert len(rc.history) == 5
         assert abs(rc.measured_bpe - 2.0) <= 0.4
+
+
+class TestLargeResults:
+    """RESULTs past the frame bound, with the bound cut to a few KiB so
+    that a (512, 16) float32 echo needs several frames."""
+
+    BOUND = 4096
+
+    @pytest.fixture
+    def small_frames(self, monkeypatch):
+        monkeypatch.setattr(framing, "MAX_PAYLOAD", self.BOUND)
+
+    @staticmethod
+    def _submit(codec, x, timeout_s=20.0, **server_kw):
+        async def run():
+            async with CloudServer(echo_features=True, **server_kw) as srv:
+                async with EdgeClient("127.0.0.1", srv.port, codec=codec,
+                                      chunk_elems=600) as client:
+                    res = await asyncio.wait_for(client.submit(x),
+                                                 timeout_s)
+                frames = srv.metrics.get(
+                    "repro_server_result_frames_total").value()
+                return res, frames
+        return asyncio.run(run())
+
+    def test_result_past_the_bound_arrives_whole(self, features,
+                                                 small_frames):
+        codec = _codec(features)
+
+        def tail(t):
+            return [np.asarray(t, np.float32).sum(axis=-1)]
+
+        res, frames = self._submit(codec, features, tail_fn=tail)
+        ref = codec.decode(codec.encode(features), shape=features.shape)
+        np.testing.assert_array_equal(np.asarray(res.arrays[0]), ref)
+        np.testing.assert_allclose(res.arrays[1], ref.sum(axis=-1),
+                                   rtol=1e-5)
+        packed = len(pack_arrays([ref, ref.sum(axis=-1)]))
+        assert packed > 8 * self.BOUND
+        assert frames == -(-packed // self.BOUND)
+
+    def test_result_under_the_bound_is_one_frame_as_before(self, features):
+        payload = pack_arrays([features])
+        assert result_frames(7, 3, payload) == [
+            encode_frame(framing.FT_RESULT, 7, 3, payload)]
+        codec = _codec(features)
+        res, frames = self._submit(codec, features)
+        assert frames == 1
+        np.testing.assert_array_equal(
+            np.asarray(res.arrays[0]),
+            codec.decode(codec.encode(features), shape=features.shape))
+
+    def test_frames_split_at_the_bound_and_join_in_order(self,
+                                                         small_frames):
+        payload = bytes(range(256)) * 33          # 8448 B: 3 frames
+        wire = result_frames(5, 9, payload)
+        reader = FrameReader()
+        reader.feed(b"".join(wire))
+        frames = list(reader)
+        assert [f.ftype for f in frames] == [framing.FT_RESULT_PART] * 2 \
+            + [framing.FT_RESULT]
+        assert [f.seq for f in frames] == [9, 10, 11]
+        assert [len(f.payload) for f in frames] == [4096, 4096, 256]
+        asm = ResultAssembler()
+        assert [asm.add(f) for f in frames[:2]] == [None, None]
+        assert asm.add(frames[2]) == payload
+        # exactly the bound is still one frame
+        assert len(result_frames(5, 9, payload[:self.BOUND])) == 1
+
+    def test_reassembly_is_bounded(self, monkeypatch):
+        monkeypatch.setattr(framing, "MAX_RESULT", 1000)
+        part = Frame(framing.FT_RESULT_PART, 1, 0, b"x" * 600)
+        asm = ResultAssembler()
+        assert asm.add(part) is None
+        with pytest.raises(FramingError, match="exceeds"):
+            asm.add(part)
+        with pytest.raises(FramingError, match="too large"):
+            result_frames(1, 0, b"x" * 1001)
+
+    @pytest.mark.parametrize("where", ["pack", "drain"])
+    def test_result_path_failure_raises_in_submit(self, features,
+                                                  monkeypatch, where):
+        import repro.transport.server as server_mod
+        from repro.serving.batcher import DecodeBatcher
+
+        def boom(*_a, **_k):
+            raise RuntimeError(f"forced failure in {where}")
+
+        if where == "pack":
+            monkeypatch.setattr(server_mod, "pack_arrays", boom)
+        else:
+            monkeypatch.setattr(DecodeBatcher, "drain", boom)
+        with pytest.raises(TransportError, match="forced failure"):
+            self._submit(_codec(features), features, timeout_s=10.0)
 
 
 class TestModelSplitHelpers:
