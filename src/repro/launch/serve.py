@@ -13,17 +13,26 @@ range per group of d_model channels, shipped in the v3 self-describing
 stream header.
 
 ``--transport loopback`` wires the split boundary through a real socket
-pair: a CloudServer thread on localhost receives the streamed, framed
-bitstream and echoes the reconstruction, and the engine round-trips
-every boundary tensor through it *between* its two jitted halves
+pair: a CloudServer in a child process (``python -m
+repro.transport.worker --echo``, pinned to the host CPU, ended with this
+process) receives the streamed, framed bitstream on localhost and echoes
+the reconstruction, and the engine round-trips every boundary tensor
+through it *between* its two jitted halves
 (``ServeEngine(codec_host_fn=...)``) -- the transport stack under a live
-serving load, safe on single-CPU hosts.
+serving load, safe on single-CPU hosts.  The edge's encode and the
+cloud's decode run in two interpreters, as in a deployment, so neither
+waits for the other's interpreter lock.
 """
 
 from __future__ import annotations
 
 import argparse
+import os
+import select
+import subprocess
+import sys
 import time
+from pathlib import Path
 
 import numpy as np
 
@@ -93,6 +102,108 @@ def _calibrate_warmup(cfg, params, args):
     return codec
 
 
+def _roundtrip(client, x):
+    """One boundary tensor through ``client``: (reconstruction, wire
+    bits per element)."""
+    res = client.submit(np.asarray(x, np.float32))
+    recon = np.asarray(res.arrays[0], np.float32).reshape(x.shape)
+    return recon, float(res.bits_per_elem)
+
+
+_CLOUD_START_TIMEOUT_S = 60.0
+_SRC = str(Path(__file__).resolve().parents[2])
+
+
+class LoopbackCloud:
+    """The loopback transport's cloud in a host-only child process.
+
+    Starts ``python -m repro.transport.worker --echo`` with the tick
+    window, metrics port, admission bound, TLS certificate and secret
+    given here, under :func:`~repro.hostenv.cpu_env` (the edge process
+    holds the chip, so the child must never initialise it), reads its
+    ``PORT`` line, and connects a :class:`SyncEdgeClient` straight to
+    that port.  The child's stdin is a pipe from this process: it exits
+    when the pipe closes, in :meth:`close` or when this process dies, so
+    it never outlives the edge.  If the child dies mid-run, the next
+    :meth:`roundtrip` raises the client's ``TransportError``.
+    """
+
+    def __init__(self, codec, chunk_elems: int, tick_ms: float = 0.0,
+                 metrics_port: int | None = None,
+                 max_queue: int | None = None,
+                 tls_cert: str | None = None, tls_key: str | None = None,
+                 secret: str | None = None, client_ssl=None):
+        from ..hostenv import cpu_env
+        from ..serving import TickConfig
+        from ..transport import SyncEdgeClient
+
+        cmd = [sys.executable, "-m", "repro.transport.worker", "--echo",
+               "--tick-ms", str(tick_ms)]
+        for flag, val in (("--metrics-port", metrics_port),
+                          ("--max-queue", max_queue),
+                          ("--tls-cert", tls_cert), ("--tls-key", tls_key),
+                          ("--secret", secret)):
+            if val is not None:
+                cmd += [flag, str(val)]
+        env = cpu_env()
+        # the child imports this package from where this process found it
+        env["PYTHONPATH"] = os.pathsep.join(
+            p for p in (_SRC, env.get("PYTHONPATH")) if p)
+        self.proc = subprocess.Popen(cmd, stdin=subprocess.PIPE,
+                                     stdout=subprocess.PIPE, text=True,
+                                     env=env)
+        try:
+            ready, _, _ = select.select([self.proc.stdout], [], [],
+                                        _CLOUD_START_TIMEOUT_S)
+            hello = self.proc.stdout.readline().split() if ready else []
+            if hello[:1] != ["PORT"]:
+                raise RuntimeError(
+                    f"cloud worker did not start within "
+                    f"{_CLOUD_START_TIMEOUT_S:.0f}s (exit code "
+                    f"{self.proc.poll()}, said {hello})")
+            self.port = int(hello[1])
+            self.metrics_port = int(hello[3]) if len(hello) > 3 else None
+            self.client = SyncEdgeClient(
+                "127.0.0.1", self.port, codec=codec,
+                chunk_elems=chunk_elems,
+                tick=(TickConfig(max_wait_s=tick_ms / 1e3)
+                      if tick_ms > 0 else None),
+                ssl=client_ssl, secret=secret)
+        except BaseException:
+            self._end_child()
+            raise
+
+    def roundtrip(self, x):
+        return _roundtrip(self.client, x)
+
+    def close(self) -> None:
+        """Read the cloud's counters, close the client, end the child."""
+        from ..transport import TransportError
+        try:
+            c = self.client.fetch_cloud_metrics()["counters"]
+        except (TransportError, ConnectionError) as e:   # child gone
+            c = None
+            print(f"cloud counters unavailable: {e}")
+        finally:
+            self.client.close()
+            self._end_child()
+        if c is not None:
+            print(f"cloud ticks: {c.get('ticks', 0)} "
+                  f"(occupancy {c.get('batch_occupancy_avg', 0.0):.2f}, "
+                  f"entropy calls {c.get('entropy_calls', 0)}, "
+                  f"bpe {c.get('bpe_avg', 0.0):.3f}, header cache "
+                  f"{c.get('header_cache', {})})")
+
+    def _end_child(self) -> None:
+        self.proc.stdin.close()        # end of file: the worker exits
+        try:
+            self.proc.wait(timeout=10)
+        except subprocess.TimeoutExpired:
+            self.proc.kill()
+            self.proc.wait()
+        self.proc.stdout.close()
+
+
 def _loopback_codec_fn(codec, chunk_elems: int, tick_ms: float = 0.0,
                        metrics_port: int | None = None,
                        workers: int = 1, max_queue: int | None = None,
@@ -101,115 +212,110 @@ def _loopback_codec_fn(codec, chunk_elems: int, tick_ms: float = 0.0,
                        secret: str | None = None):
     """Split-boundary host hook that streams every tensor over localhost.
 
-    Starts a CloudServer (echoing reconstructions) on a daemon thread and
-    returns a *host* round-trip ``x -> (recon, bits_per_elem)`` for
-    ``ServeEngine(codec_host_fn=...)``: the engine runs each stage as two
-    jitted halves split at the boundary and calls this eagerly in
-    between, so the client's own jax encode never executes beneath an
-    in-flight jitted program.  (The old ``io_callback`` hookup deadlocked
-    on single-CPU hosts: the callback held XLA's only dispatch thread
-    while the nested encode waited for it.  Running the round-trip
-    *between* programs removes that cycle structurally --
-    tests/test_serve_loopback.py pins it on 1 CPU.)  The reported rate is
-    the true wire bits/element (frames, headers and all).
+    Returns a *host* round trip ``x -> (recon, bits_per_elem)`` for
+    ``ServeEngine(codec_host_fn=...)`` and its clean-up: the engine runs
+    each stage as two jitted halves split at the boundary and calls the
+    round trip eagerly in between, so the client's own jax encode never
+    executes beneath an in-flight jitted program.  (The old
+    ``io_callback`` hookup deadlocked on single-CPU hosts: the callback
+    held XLA's only dispatch thread while the nested encode waited for
+    it.  Running the round-trip *between* programs removes that cycle
+    structurally -- tests/test_serve_loopback.py pins it on 1 CPU.)  The
+    reported rate is the true wire bits/element (frames, headers and
+    all).
 
-    The server always runs the cross-session tick drain (one batched
-    entropy call per tick); ``tick_ms`` sets the tick window.  The
-    engine keeps one tensor in flight per boundary crossing, so the
-    default window is 0 (drain as soon as the loop is idle) and client-
-    side encode coalescing only engages for ``tick_ms > 0``.
+    The cloud (``workers == 1``) is a :class:`LoopbackCloud`: a
+    ``repro.transport.worker`` child process echoing reconstructions, so
+    its decode of one chunk overlaps the edge's encode of the next
+    instead of waiting for this process's interpreter lock.  It always
+    runs the cross-session tick drain (one batched entropy call per
+    tick); ``tick_ms`` sets the tick window.  The engine keeps one
+    tensor in flight per boundary crossing, so the default window is 0
+    (drain as soon as the loop is idle) and client-side encode
+    coalescing only engages for ``tick_ms > 0``.
 
     Hardened-serving knobs (DESIGN.md, "Hardened scale-out serving"):
     ``workers > 1`` puts a session-affine :class:`Dispatcher` over a
-    pool of in-process CloudServers (worker kill/restart tolerant; the
-    client gets a retry policy so restarts replay transparently);
-    ``max_queue`` bounds in-flight sessions (BUSY shedding);
-    ``tls_cert``/``tls_key`` wrap the edge-facing socket in TLS; and
-    ``secret`` requires the authenticated HELLO handshake.
+    pool of in-process CloudServers on a thread of this process (worker
+    kill/restart tolerant; the client gets a retry policy so restarts
+    replay transparently); ``max_queue`` bounds in-flight sessions (BUSY
+    shedding); ``tls_cert``/``tls_key`` wrap the edge-facing socket in
+    TLS; and ``secret`` requires the authenticated HELLO handshake.
     """
-    import asyncio
     import ssl as ssl_mod
+
+    client_ssl = None
+    if tls_cert is not None:
+        # self-signed deployments pin the cert itself as the CA; the
+        # hostname check is skipped (loopback certs rarely carry SANs)
+        client_ssl = ssl_mod.create_default_context(cafile=tls_cert)
+        client_ssl.check_hostname = False
+    tls = ", TLS" if tls_cert is not None else ""
+    auth = ", authenticated" if secret is not None else ""
+
+    if workers == 1:
+        cloud = LoopbackCloud(codec, chunk_elems, tick_ms,
+                              metrics_port=metrics_port,
+                              max_queue=max_queue, tls_cert=tls_cert,
+                              tls_key=tls_key, secret=secret,
+                              client_ssl=client_ssl)
+        print(f"loopback transport: streaming split tensors via cloud "
+              f"worker pid {cloud.proc.pid} on 127.0.0.1:{cloud.port} (tick "
+              f"window {tick_ms:.1f}ms{tls}{auth})")
+        if cloud.metrics_port is not None:
+            print(f"metrics: http://127.0.0.1:{cloud.metrics_port}/metrics")
+        return cloud.roundtrip, cloud.close
+
+    import asyncio
     import threading
 
     from ..serving import TickConfig
     from ..transport import (CloudServer, Dispatcher, RetryPolicy,
                              SyncEdgeClient)
+    from ..transport.worker import server_ssl_context
 
     loop = asyncio.new_event_loop()
-    threading.Thread(target=loop.run_forever, name="cloud-server",
-                     daemon=True).start()
+    thread = threading.Thread(target=loop.run_forever, name="dispatcher",
+                              daemon=True)
+    thread.start()
     tick = TickConfig(max_wait_s=tick_ms / 1e3)
-
-    server_ssl = client_ssl = None
-    if tls_cert is not None:
-        server_ssl = ssl_mod.SSLContext(ssl_mod.PROTOCOL_TLS_SERVER)
-        server_ssl.load_cert_chain(tls_cert, tls_key or tls_cert)
-        # self-signed deployments pin the cert itself as the CA; the
-        # hostname check is skipped (loopback certs rarely carry SANs)
-        client_ssl = ssl_mod.create_default_context(cafile=tls_cert)
-        client_ssl.check_hostname = False
-
-    retry = None
-    if workers > 1:
-        server = Dispatcher(
-            workers=workers,
-            worker_factory=lambda i: CloudServer(echo_features=True,
-                                                 tick=tick),
-            max_queue=max_queue, ssl=server_ssl, secret=secret)
-        retry = RetryPolicy()      # worker restarts replay transparently
-    else:
-        server = CloudServer(echo_features=True, tick=tick,
-                             metrics_port=metrics_port,
-                             max_queue=max_queue, ssl=server_ssl,
-                             secret=secret)
+    server = Dispatcher(
+        workers=workers,
+        worker_factory=lambda i: CloudServer(echo_features=True, tick=tick),
+        max_queue=max_queue,
+        ssl=(server_ssl_context(tls_cert, tls_key)
+             if tls_cert is not None else None),
+        secret=secret)
     asyncio.run_coroutine_threadsafe(server.start(), loop).result()
     client = SyncEdgeClient("127.0.0.1", server.port, codec=codec,
                             chunk_elems=chunk_elems,
                             tick=tick if tick_ms > 0 else None,
-                            ssl=client_ssl, secret=secret, retry=retry)
-    kind = (f"dispatcher x{workers} workers" if workers > 1
-            else "cloud server")
-    print(f"loopback transport: streaming split tensors via {kind} on "
-          f"127.0.0.1:{server.port} (tick window {tick_ms:.1f}ms"
-          f"{', TLS' if server_ssl is not None else ''}"
-          f"{', authenticated' if secret is not None else ''})")
-    if getattr(server, "metrics_port", None) is not None:
-        print(f"metrics: http://127.0.0.1:{server.metrics_port}/metrics")
-
-    def host_roundtrip(x):
-        res = client.submit(np.asarray(x, np.float32))
-        recon = np.asarray(res.arrays[0], np.float32).reshape(x.shape)
-        return recon, float(res.bits_per_elem)
+                            ssl=client_ssl, secret=secret,
+                            retry=RetryPolicy())   # restarts replay
+    print(f"loopback transport: streaming split tensors via dispatcher "
+          f"x{workers} workers on 127.0.0.1:{server.port} (tick window "
+          f"{tick_ms:.1f}ms{tls}{auth})")
 
     def cleanup():
-        if workers > 1:
-            snap = server.metrics.snapshot()
+        snap = server.metrics.snapshot()
 
-            def val(name):
-                s = snap.get(name, {}).get("series", [])
-                return s[0]["value"] if s else 0
+        def val(name):
+            s = snap.get(name, {}).get("series", [])
+            return s[0]["value"] if s else 0
 
-            client.close()
-            asyncio.run_coroutine_threadsafe(server.close(), loop).result()
-            loop.call_soon_threadsafe(loop.stop)
-            print(f"dispatcher: "
-                  f"{val('repro_dispatcher_routed_sessions_total'):.0f} "
-                  f"sessions routed, "
-                  f"{val('repro_dispatcher_worker_restarts_total'):.0f} "
-                  f"worker restarts, "
-                  f"{val('repro_dispatcher_shed_sessions_total'):.0f} shed")
-            return
-        counters = server.counters
         client.close()
         asyncio.run_coroutine_threadsafe(server.close(), loop).result()
         loop.call_soon_threadsafe(loop.stop)
-        print(f"cloud ticks: {counters.get('ticks', 0)} "
-              f"(occupancy {counters.get('batch_occupancy_avg', 0.0):.2f}, "
-              f"entropy calls {counters.get('entropy_calls', 0)}, "
-              f"bpe {counters.get('bpe_avg', 0.0):.3f}, header cache "
-              f"{counters.get('header_cache', {})})")
+        thread.join(timeout=5)
+        loop.close()
+        print(f"dispatcher: "
+              f"{val('repro_dispatcher_routed_sessions_total'):.0f} "
+              f"sessions routed, "
+              f"{val('repro_dispatcher_worker_restarts_total'):.0f} "
+              f"worker restarts, "
+              f"{val('repro_dispatcher_shed_sessions_total'):.0f} shed")
 
-    return host_roundtrip, cleanup
+    return (lambda x: _roundtrip(client, x)), cleanup
 
 
 def build_parser() -> argparse.ArgumentParser:

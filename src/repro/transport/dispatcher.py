@@ -301,8 +301,10 @@ class Dispatcher:
             cmd = list(self._worker_cmd) + ["--host", "127.0.0.1",
                                             "--port", "0"]
             # host-only worker: it must not contend for the chip the
-            # dispatcher's own process may hold
-            w.proc = subprocess.Popen(cmd, stdout=subprocess.PIPE,
+            # dispatcher's own process may hold.  It exits at its stdin
+            # pipe's end, so it dies with this process
+            w.proc = subprocess.Popen(cmd, stdin=subprocess.PIPE,
+                                      stdout=subprocess.PIPE,
                                       stderr=subprocess.DEVNULL, text=True,
                                       env=cpu_env())
             line = await asyncio.wait_for(
@@ -327,6 +329,7 @@ class Dispatcher:
                 await asyncio.to_thread(w.proc.wait, 10)
             except Exception:                       # noqa: BLE001
                 pass
+            w.proc.stdin.close()
             w.proc = None
         if w.server is not None:
             if graceful:

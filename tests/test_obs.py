@@ -502,26 +502,41 @@ class TestEngineTelemetry:
 
 
 @pytest.fixture(scope="module")
-def loopback_engine():
-    """A smoke-width split engine whose boundary round trip runs through
-    ``serve.py``'s loopback transport (edge client, socket, cloud server
-    thread)."""
-    import jax
-
+def loopback_cloud():
+    """``serve.py``'s loopback transport (edge client, socket, cloud
+    worker process).  The child traces its own spans
+    (``REPRO_OBS_TRACE=1``) and serves them at ``/events``."""
     from repro.launch.serve import _loopback_codec_fn
-    from repro.models import init_params
-    from repro.serving import ServeEngine
-    cfg = _tiny_cfg()
-    params = init_params(cfg, jax.random.PRNGKey(0))
     rng = np.random.default_rng(3)
     codec = calibrate(CodecConfig(n_levels=4, clip_mode="minmax",
                                   constrain_cmin_zero=False),
                       samples=rng.normal(0, 1, 4096).astype(np.float32))
-    host_fn, cleanup = _loopback_codec_fn(codec, 1 << 16)
-    eng = ServeEngine(cfg, params, slots=2, max_seq=32,
-                      codec_host_fn=host_fn)
-    yield eng
+    with pytest.MonkeyPatch.context() as mp:
+        mp.setenv("REPRO_OBS_TRACE", "1")
+        host_fn, cleanup = _loopback_codec_fn(codec, 1 << 16,
+                                              metrics_port=0)
+    yield host_fn.__self__
     cleanup()
+
+
+def _cloud_events(cloud) -> list[dict]:
+    url = f"http://127.0.0.1:{cloud.metrics_port}/events"
+    with urllib.request.urlopen(url, timeout=10) as r:
+        return json.loads(r.read())["events"]
+
+
+@pytest.fixture(scope="module")
+def loopback_engine(loopback_cloud):
+    """A smoke-width split engine whose boundary round trip runs through
+    ``loopback_cloud``."""
+    import jax
+
+    from repro.models import init_params
+    from repro.serving import ServeEngine
+    cfg = _tiny_cfg()
+    params = init_params(cfg, jax.random.PRNGKey(0))
+    return ServeEngine(cfg, params, slots=2, max_seq=32,
+                       codec_host_fn=loopback_cloud.roundtrip)
 
 
 def _serve(eng, n_new=4):
@@ -549,7 +564,8 @@ def _traced(eng, n_new=4, jax_trace=False):
 class TestServedStepTracing:
     STAGES = ("split_head", "split_roundtrip", "split_tail")
 
-    def test_stage_spans_of_each_decode_step(self, loopback_engine):
+    def test_stage_spans_of_each_decode_step(self, loopback_engine,
+                                             loopback_cloud):
         events, steps = _traced(loopback_engine)
         assert steps == 3
         by_id = {e["span_id"]: e for e in events}
@@ -563,7 +579,9 @@ class TestServedStepTracing:
             assert [e["stage"] for e in trio] == list(self.STAGES)
             assert len({e["kind"] for e in trio}) == 1
         # the edge client's spans parent under the engine's round trip
-        # and carry the session id the cloud's spans carry
+        # and carry the session id the cloud's spans carry; the cloud's
+        # spans of this run are those its process opened after this
+        # run's first edge span (both stamp ``time.time()``)
         rts = {e["span_id"] for e in events
                if e["stage"] == "split_roundtrip"}
         edge = [e for e in events
@@ -571,8 +589,11 @@ class TestServedStepTracing:
         assert len(edge) == 2 * len(rts)
         assert {e["parent_id"] for e in edge} == rts
         sessions = {e["session"] for e in edge}
-        cloud = {e["session"].split(":")[1] for e in events
-                 if e["stage"] == "socket_write" and ":" in e["session"]}
+        t_run = min(e["t_start"] for e in edge)
+        cloud = {e["session"].split(":")[1]
+                 for e in _cloud_events(loopback_cloud)
+                 if e["stage"] == "socket_write" and ":" in e["session"]
+                 and e["t_start"] >= t_run}
         assert sessions == cloud and len(sessions) == len(rts)
         for e in edge:
             rt = by_id[e["parent_id"]]
