@@ -47,7 +47,6 @@ compares the JSON against the committed baseline in CI.
 from __future__ import annotations
 
 import json
-import os
 import sys
 import time
 
@@ -265,38 +264,6 @@ def bench_codec(quick: bool = False) -> list[str]:
                            "unfused reference path")
     t_dec_e2e = _best(lambda: codec.decode(blob_fused, shape=feats.shape))
 
-    # thread-sharded rANS (REPRO_RANS_THREADS): independent element-range
-    # shards coded on a pool.  Reported honestly: on GIL-bound numpy builds
-    # this loses to serial dispatch; the row records the measured ratio.
-    n_threads = min(2, os.cpu_count() or 1)
-    os.environ["REPRO_RANS_THREADS"] = str(n_threads)
-    try:
-        cabac.encode_indices(idx[:1000], 4, mode="rans_sharded")  # warm pool
-        t_enc_shard = _best(
-            lambda: cabac.encode_indices(idx, 4, mode="rans_sharded"))
-        blob_shard = cabac.encode_indices(idx, 4, mode="rans_sharded")
-        t_dec_shard = _best(
-            lambda: cabac.decode_indices(blob_shard, idx.size, 4))
-        assert (cabac.decode_indices(blob_shard, idx.size, 4) == idx).all()
-    finally:
-        del os.environ["REPRO_RANS_THREADS"]
-    bpe_shard = 8 * len(blob_shard) / idx.size
-
-    # process-sharded rANS (coder id 3): real cores, opt-in; the fork +
-    # pickle cost only pays off for multi-MB payloads
-    n_procs = min(2, os.cpu_count() or 1)
-    os.environ["REPRO_RANS_PROCS"] = str(n_procs)
-    try:
-        cabac.encode_indices(idx[:1000], 4, mode="rans_proc")  # warm pool
-        t_enc_proc = _best(
-            lambda: cabac.encode_indices(idx, 4, mode="rans_proc"))
-        blob_proc = cabac.encode_indices(idx, 4, mode="rans_proc")
-        t_dec_proc = _best(
-            lambda: cabac.decode_indices(blob_proc, idx.size, 4))
-        assert (cabac.decode_indices(blob_proc, idx.size, 4) == idx).all()
-    finally:
-        del os.environ["REPRO_RANS_PROCS"]
-
     enc_speedup = t_enc_serial / t_enc_rans
     dec_speedup = t_dec_serial / t_dec_rans
     bpe_serial = 8 * len(blob_serial) / idx.size
@@ -416,13 +383,6 @@ def bench_codec(quick: bool = False) -> list[str]:
         "encode_unfused_s": t_enc_unfused,
         "decode_e2e_s": t_dec_e2e,
         "fused_identical": fused_identical,
-        "rans_shard_threads": n_threads,
-        "encode_rans_sharded_s": t_enc_shard,
-        "decode_rans_sharded_s": t_dec_shard,
-        "bits_per_elem_rans_sharded": bpe_shard,
-        "rans_shard_procs": n_procs,
-        "encode_rans_proc_s": t_enc_proc,
-        "decode_rans_proc_s": t_dec_proc,
         "encode_speedup": enc_speedup,
         "decode_speedup": dec_speedup,
         "encode_speedup_ge_20x": enc_speedup >= 20.0,
@@ -469,11 +429,6 @@ def bench_codec(quick: bool = False) -> list[str]:
         f"vs_unfused={t_enc_unfused/t_enc_fused:.2f}x,identical=True",
         f"codec_kernel_fused_micro,{micro['kernel_fused_s']*1e6:.0f},"
         f"vs_separate={micro['kernel_fused_vs_unfused']:.2f}x",
-        f"codec_encode_rans_sharded,{t_enc_shard*1e6:.0f},"
-        f"threads={n_threads},vs_rans={t_enc_rans/t_enc_shard:.2f}x,"
-        f"bpe={bpe_shard:.3f}",
-        f"codec_encode_rans_proc,{t_enc_proc*1e6:.0f},"
-        f"procs={n_procs},vs_rans={t_enc_rans/t_enc_proc:.2f}x",
     ]
     for n_levels, v in grain_bpe.items():
         rows.append(f"codec_granularity_N{n_levels},0,"

@@ -12,6 +12,10 @@ Two interchangeable host coders sit behind :func:`encode_indices` /
     encode and 1.6 Melem/s decode on a 16,384-element decode-step
     crossing at 0.8 bits/element, on one core of a TPU v5e host (3.2x
     and 2.7x the per-bit loops there).
+    Its exponentially adapting estimate codes about 0.011 bits a TU bin
+    above the sample's empirical entropy (alpha / (2 (2 - alpha) ln 2)
+    at alpha = 2^-5), under 0.02 on every plane measured (P(1) from 0
+    to 1/2), plus up to 32 bits of learning a context.
     It only stays on the hot path for small payloads
     (< ``_SERIAL_CUTOFF_BITS`` TU bits) where its 4-byte flush beats the
     vectorized coder's per-lane overhead;
@@ -22,7 +26,10 @@ Two interchangeable host coders sit behind :func:`encode_indices` /
     1M-element tensor of ``benchmarks/bench_codec.py``, on one core of a
     TPU v5e host.
 
-A one-byte coder id prefixes the payload so the decoder self-selects.
+A one-byte coder id prefixes the payload so the decoder self-selects:
+0 serial, 1 rANS, and 2-4 sharded rANS containers.  Nothing here writes
+ids 2 and 3 any more, but streams that carry them still decode; id 4 is
+coded on device (``repro.kernels.rans_coder``).
 Streams written by the seed (no id byte) are still readable through
 :func:`decode_indices_serial`, which ``FeatureCodec.decode`` uses for
 legacy headers.  See DESIGN.md for the layout.
@@ -221,16 +228,15 @@ class BinaryArithmeticDecoder:
 
 _CODER_SERIAL = 0
 _CODER_RANS = 1
+# Sharded containers: element shards, each its own rANS stream.  Ids 2
+# and 3 only decode (streams already written must stay readable); coder
+# 4 is the same layout with one shard, coded on device.
 _CODER_RANS_SHARDED = 2
-_CODER_RANS_PROC = 3    # same shard layout as 2, coded on a process pool
-_CODER_RANS_DEVICE = 4  # single-shard coder-2 layout, coded on device
+_CODER_RANS_PROC = 3
+_CODER_RANS_DEVICE = 4
 # Below this many TU bits the serial coder's 4-byte flush undercuts the
 # vectorized coder's per-lane state overhead, and the python loop is cheap.
 _SERIAL_CUTOFF_BITS = 1 << 16
-# Above this many TU bits "auto" shards the payload across the rANS thread
-# or process pool (multi-MB activation tensors); below it the per-shard
-# state/table duplication and pool dispatch are not worth it.
-_SHARD_MIN_BITS = 1 << 21
 
 
 def encode_indices_serial(idx: np.ndarray, n_levels: int) -> bytes:
@@ -274,25 +280,10 @@ def _decode_planes(next_plane, n_elems: int, n_levels: int) -> np.ndarray:
 
 
 def _shard_bounds(n_elems: int, n_shards: int) -> list[tuple[int, int]]:
-    """Contiguous element ranges for sharded coding (last may be short)."""
+    """Contiguous element ranges of a sharded payload (last may be short)."""
     per = -(-n_elems // max(1, n_shards))
     return [(s * per, min((s + 1) * per, n_elems))
             for s in range(n_shards) if s * per < n_elems]
-
-
-def _encode_shard_worker(args) -> bytes:
-    """Encode one element shard to a standalone rANS stream (module-level
-    so the process pool can pickle it)."""
-    seg, n_levels = args
-    from .binarization import index_to_context_bits
-    return rans.encode_planes(index_to_context_bits(seg, n_levels))
-
-
-def _decode_shard_worker(args) -> np.ndarray:
-    """Decode one standalone shard stream (module-level, picklable)."""
-    blob, count, n_levels = args
-    d = rans.PlaneStreamDecoder(blob)
-    return _decode_planes(lambda n, j: d.next_plane(n), count, n_levels)
 
 
 def _shard_header(blobs: list[bytes]) -> bytes:
@@ -301,94 +292,73 @@ def _shard_header(blobs: list[bytes]) -> bytes:
     return head
 
 
-def _split_shards(body: bytes, n_elems: int, n_levels: int) -> list:
-    """Parse a sharded body into ``_decode_shard_worker`` jobs."""
+def _split_shards(body: bytes, n_elems: int) -> list[tuple[bytes, int]]:
+    """Parse a sharded body into (shard stream, element count) pairs."""
     (n_shards,) = struct.unpack_from("<H", body)
     lens = struct.unpack_from(f"<{n_shards}I", body, 2)
     bounds = _shard_bounds(n_elems, n_shards)
     if len(bounds) != n_shards:
         raise ValueError("shard count does not match element count")
     off = 2 + 4 * n_shards
-    jobs = []
+    shards = []
     for (a, b), ln in zip(bounds, lens):
-        jobs.append((body[off:off + ln], b - a, n_levels))
+        shards.append((body[off:off + ln], b - a))
         off += ln
-    return jobs
+    return shards
 
 
 def wrap_device_blob(blob: bytes) -> bytes:
     """Coder-4 container for one device-coded (or host-fallback) rANS
-    blob: the single-shard coder-2 layout under a distinct id byte, so
-    device-coded streams are byte-identical to
-    ``_encode_rans_sharded(idx, n_levels, n_shards=1)`` past the id.
-    An empty ``blob`` means an empty stream (zero shards, like coder 2's
-    empty payload)."""
+    blob: the sharded layout of ids 2 and 3 with a single shard, under
+    its own id byte.  An empty ``blob`` means an empty stream (zero
+    shards)."""
     if not blob:
         return bytes([_CODER_RANS_DEVICE]) + struct.pack("<H", 0)
     return bytes([_CODER_RANS_DEVICE]) + _shard_header([blob]) + blob
 
 
-def _encode_rans_sharded(idx: np.ndarray, n_levels: int, n_shards: int,
-                         coder_id: int = _CODER_RANS_SHARDED) -> bytes:
-    """Shard elements into independent rANS streams coded on the thread
-    (coder id 2) or process (coder id 3) pool.  Layout: id byte |
-    <H> n_shards | n_shards x <I> byte length | concatenated shard
-    streams.  Each shard flushes its own coder state, so shards decode
-    independently (and in parallel); both ids share one byte layout, so
-    the shard bytes are identical whichever pool coded them."""
-    bounds = _shard_bounds(idx.size, n_shards)
-    jobs = [(idx[a:b], n_levels) for a, b in bounds]
-    if coder_id == _CODER_RANS_PROC:
-        blobs = rans.proc_map(_encode_shard_worker, jobs, n_shards)
-    else:
-        blobs = rans.parallel_map(_encode_shard_worker, jobs)
-    return bytes([coder_id]) + _shard_header(blobs) + b"".join(blobs)
-
-
-def _decode_rans_sharded(body: bytes, n_elems: int, n_levels: int,
-                         use_procs: bool = False) -> np.ndarray:
-    jobs = _split_shards(body, n_elems, n_levels)
-    if not jobs:
+def _decode_rans_sharded(body: bytes, n_elems: int,
+                         n_levels: int) -> np.ndarray:
+    """Decode a sharded body (coder ids 2, 3 and 4).  Layout: <H>
+    n_shards | n_shards x <I> byte length | concatenated shard streams,
+    each an independently flushed :func:`rans.encode_planes` stream over
+    its element range."""
+    shards = _split_shards(body, n_elems)
+    if not shards:
         return np.zeros(n_elems, dtype=np.int32)
-    if use_procs:
-        # a proc-coded stream decodes on the pool when one is configured
-        # (and in-process otherwise: ids are wire format, not policy)
-        n = rans.proc_workers() or 1
-        return np.concatenate(rans.proc_map(_decode_shard_worker, jobs, n))
-    return np.concatenate(rans.parallel_map(_decode_shard_worker, jobs))
+    out = []
+    for blob, count in shards:
+        d = rans.PlaneStreamDecoder(blob)
+        out.append(_decode_planes(lambda n, j: d.next_plane(n),
+                                  count, n_levels))
+    return np.concatenate(out)
+
+
+def _pick_coder(seg: np.ndarray, n_levels: int) -> str:
+    """The ``auto`` rule: ``"serial"`` below ``_SERIAL_CUTOFF_BITS`` TU
+    bits, ``"rans"`` at or above it.  Every element codes at least one
+    TU bit, so the exact count (a full pass over the indices) is only
+    taken when the element count alone cannot settle the choice."""
+    from .binarization import total_tu_bits
+    if seg.size >= _SERIAL_CUTOFF_BITS:
+        return "rans"
+    if total_tu_bits(seg, n_levels) < _SERIAL_CUTOFF_BITS:
+        return "serial"
+    return "rans"
 
 
 def encode_indices(idx: np.ndarray, n_levels: int, mode: str = "auto") -> bytes:
     """TU-binarize + entropy-code a flat index array (plane-major order).
 
-    ``mode``: "auto" picks the serial coder below the size cutoff, the
-    vectorized coder above it, and -- for multi-MB payloads -- the
-    process-sharded coder when ``REPRO_RANS_PROCS`` configures workers,
-    else the thread-sharded coder when the thread pool has more than one;
-    "serial" / "rans" / "rans_sharded" / "rans_proc" force a coder.  The
-    payload starts with a one-byte coder id; :func:`decode_indices`
-    dispatches on it.
+    ``mode``: "auto" picks the coder from the payload size
+    (:func:`_pick_coder`); "serial" / "rans" force one.  The payload
+    starts with a one-byte coder id; :func:`decode_indices` dispatches
+    on it.
     """
     from .binarization import index_to_context_bits
     idx = np.asarray(idx).ravel()
     if mode == "auto":
-        # every element codes at least one TU bit, so the exact bit count
-        # (a full pass over the indices) is only needed when the element
-        # count alone cannot settle the choice
-        pooled = rans.proc_workers() > 1 or rans.rans_threads() > 1
-        if idx.size >= _SERIAL_CUTOFF_BITS and not pooled:
-            mode = "rans"
-        else:
-            from .binarization import total_tu_bits
-            total = total_tu_bits(idx, n_levels)
-            if total < _SERIAL_CUTOFF_BITS:
-                mode = "serial"
-            elif total >= _SHARD_MIN_BITS and rans.proc_workers() > 1:
-                mode = "rans_proc"
-            elif total >= _SHARD_MIN_BITS and rans.rans_threads() > 1:
-                mode = "rans_sharded"
-            else:
-                mode = "rans"
+        mode = _pick_coder(idx, n_levels)
     if mode == "serial":
         enc = BinaryArithmeticEncoder(n_contexts=max(n_levels - 1, 1))
         for j, plane in enumerate(index_to_context_bits(idx, n_levels)):
@@ -397,18 +367,6 @@ def encode_indices(idx: np.ndarray, n_levels: int, mode: str = "auto") -> bytes:
     if mode == "rans":
         return bytes([_CODER_RANS]) \
             + rans.encode_planes(index_to_context_bits(idx, n_levels))
-    if mode == "rans_sharded":
-        return _encode_rans_sharded(idx, n_levels, rans.rans_threads())
-    if mode == "rans_proc":
-        return _encode_rans_sharded(idx, n_levels,
-                                    max(2, rans.proc_workers()),
-                                    coder_id=_CODER_RANS_PROC)
-    if mode == "rans_device":
-        # in-graph coder (id 4); on host arrays this round-trips through
-        # the device, so it is mainly the backends' emit_wire path that
-        # reaches it with data already resident
-        from ..kernels.rans_coder import encode_indices_device
-        return encode_indices_device(idx, n_levels)
     raise ValueError(f"unknown coder mode {mode!r}")
 
 
@@ -443,22 +401,17 @@ def encode_indices_batch(segments: list[np.ndarray], n_levels,
     all segments that land on the vectorized coder share one batched rANS
     step loop (:func:`repro.core.rans.encode_planes_batch`) -- the
     chunked-stream encoder's per-chunk python dispatch collapses to one
-    loop per batch.  ``auto`` keeps the serial coder for small segments;
-    the thread-sharded coder is not used here (batching already amortizes
-    the dispatch the pool would target).  ``n_levels`` may be a scalar or
+    loop per batch.  ``auto`` picks each segment's coder by the same
+    rule as :func:`encode_indices`.  ``n_levels`` may be a scalar or
     one value per segment (cross-session ticks mix quantizer rungs).
     """
-    from .binarization import index_to_context_bits, total_tu_bits
+    from .binarization import index_to_context_bits
     segments = [np.asarray(s).ravel() for s in segments]
     levels = _levels_list(n_levels, len(segments))
     out: list[bytes | None] = [None] * len(segments)
     rans_ids = []
     for i, seg in enumerate(segments):
-        m = mode
-        if m == "auto":
-            m = "rans" if seg.size >= _SERIAL_CUTOFF_BITS else \
-                ("serial" if total_tu_bits(seg, levels[i])
-                 < _SERIAL_CUTOFF_BITS else "rans")
+        m = _pick_coder(seg, levels[i]) if mode == "auto" else mode
         if m == "rans":
             rans_ids.append(i)
         else:
@@ -481,10 +434,9 @@ def decode_indices(data: bytes, n_elems: int, n_levels: int) -> np.ndarray:
         dec = rans.PlaneStreamDecoder(body)
         return _decode_planes(lambda n, j: dec.next_plane(n),
                               n_elems, n_levels)
-    if coder in (_CODER_RANS_SHARDED, _CODER_RANS_DEVICE):
+    if coder in (_CODER_RANS_SHARDED, _CODER_RANS_PROC,
+                 _CODER_RANS_DEVICE):
         return _decode_rans_sharded(body, n_elems, n_levels)
-    if coder == _CODER_RANS_PROC:
-        return _decode_rans_sharded(body, n_elems, n_levels, use_procs=True)
     raise ValueError(f"unknown coder id {coder}")
 
 
@@ -497,11 +449,11 @@ def decode_indices_batch(payloads: list[bytes], counts: list[int],
     one batched step loop per TU plane round
     (:class:`repro.core.rans.BatchPlaneDecoder`) -- the receive side's
     per-chunk python dispatch collapses the same way the batched encoder
-    collapsed the send side's.  Serial and sharded payloads decode
-    individually (they are small or already parallel).  ``n_levels`` may
-    be a scalar or one value per payload: a cross-session drain mixes
-    streams at different quantizer rungs in one call, and a stream whose
-    TU planes are exhausted simply stops consuming plane rounds.
+    collapsed the send side's.  Serial and multi-shard payloads decode
+    individually.  ``n_levels`` may be a scalar or one value per
+    payload: a cross-session drain mixes streams at different quantizer
+    rungs in one call, and a stream whose TU planes are exhausted simply
+    stops consuming plane rounds.
     """
     levels = _levels_list(n_levels, len(payloads))
     out: list[np.ndarray | None] = [None] * len(payloads)

@@ -453,6 +453,17 @@ def flush_decoders(decoders) -> tuple[int, int, list]:
     return len(payloads), sum(counts), []
 
 
+def use_device_entropy(device_entropy: bool | None, coder_mode: str) -> bool:
+    """Resolve the device-resident entropy opt-in (coder id 4): an
+    explicit argument wins; otherwise ``REPRO_ENTROPY_DEVICE=1`` turns it
+    on whenever the coder choice is ours (``coder_mode == "auto"``) --
+    pinned coder modes keep their exact wire bytes."""
+    if device_entropy is not None:
+        return bool(device_entropy)
+    return coder_mode == "auto" \
+        and os.environ.get("REPRO_ENTROPY_DEVICE") == "1"
+
+
 @dataclasses.dataclass
 class FeatureCodec:
     """Calibrated codec instance.  Build with :func:`calibrate`.
@@ -672,16 +683,6 @@ class FeatureCodec:
                                          self.bits_per_index(),
                                          want_hist=want_hist)
 
-    def _device_entropy(self, device_entropy, coder_mode: str) -> bool:
-        """Resolve the device-resident entropy opt-in: an explicit
-        argument wins; otherwise ``REPRO_ENTROPY_DEVICE=1`` turns it on
-        whenever the coder choice is ours (``coder_mode == "auto"``) --
-        pinned coder modes keep their exact wire bytes."""
-        if device_entropy is not None:
-            return bool(device_entropy)
-        return coder_mode == "auto" \
-            and os.environ.get("REPRO_ENTROPY_DEVICE") == "1"
-
     def encode(self, x: np.ndarray, coder_mode: str = "auto",
                fused: bool = True, device_entropy: bool | None = None
                ) -> bytes:
@@ -700,7 +701,7 @@ class FeatureCodec:
         """
         x = np.asarray(x, np.float32)
         header, _ = self._header(x)
-        if fused and self._device_entropy(device_entropy, coder_mode):
+        if fused and use_device_entropy(device_entropy, coder_mode):
             payload, _ = self.backend.encode_fused(
                 jnp.asarray(x), self.spec(), self.bits_per_index(),
                 emit_wire=True)
@@ -774,7 +775,7 @@ class FeatureCodec:
         x = np.asarray(x, np.float32)
         if self.plan is not None:
             chunk_elems = self.plan.align_chunk_elems(chunk_elems, x.shape)
-        if self._device_entropy(device_entropy, coder_mode):
+        if use_device_entropy(device_entropy, coder_mode):
             # device-resident entropy: one emit_wire pass yields every
             # chunk's coder-id-4 payload; no index tensor ever crosses
             n = int(x.size)

@@ -37,9 +37,7 @@ cost of the >=20 Melem/s host hot path (both measured in bench_codec.py).
 
 from __future__ import annotations
 
-import os
 import struct
-from concurrent.futures import ThreadPoolExecutor
 
 import numpy as np
 
@@ -54,113 +52,6 @@ _S64 = np.uint64(_PROB_BITS)
 _EMIT_SHIFT = np.uint64(32 - _PROB_BITS)
 _MASK_S = np.uint64(_M - 1)
 _MASK_W = np.uint64(0xFFFF)
-
-
-def rans_threads() -> int:
-    """Worker count for sharded plane coding (``REPRO_RANS_THREADS``).
-
-    Defaults to 1 (sharding off): the step loop is numpy-dispatch bound,
-    and on CPython builds whose numpy holds the GIL through the small
-    per-step ops a thread pool is measured *slower* than serial (see
-    ``BENCH_codec.json``'s ``encode_rans_sharded`` row).  Opt in on
-    hosts with a GIL-releasing numpy / free-threaded interpreter, where
-    the independent shards scale to ``min(threads, shards)`` cores.
-    """
-    env = os.environ.get("REPRO_RANS_THREADS", "").strip()
-    if env:
-        return max(1, int(env))
-    return 1
-
-
-_POOL: ThreadPoolExecutor | None = None
-_POOL_SIZE = 0
-
-
-def _get_pool(n: int) -> ThreadPoolExecutor:
-    global _POOL, _POOL_SIZE
-    if _POOL is None or _POOL_SIZE < n:
-        if _POOL is not None:
-            _POOL.shutdown(wait=False)
-        _POOL = ThreadPoolExecutor(max_workers=n, thread_name_prefix="rans")
-        _POOL_SIZE = n
-    return _POOL
-
-
-def parallel_map(fn, items, n_threads: int | None = None) -> list:
-    """Map ``fn`` over ``items`` on the rANS thread pool (ordered results).
-
-    Falls back to a plain loop for a single item or a single-thread
-    configuration, so callers need no special casing.
-    """
-    items = list(items)
-    n = rans_threads() if n_threads is None else n_threads
-    n = min(n, len(items))
-    if n <= 1:
-        return [fn(it) for it in items]
-    return list(_get_pool(n).map(fn, items))
-
-
-def proc_workers() -> int:
-    """Worker count for process-pool shard coding (``REPRO_RANS_PROCS``).
-
-    Defaults to 0 (off): worker processes pay fork + pickle transfer per
-    shard, which only wins for multi-MB payloads on hosts whose numpy
-    holds the GIL through the step loop (where the thread pool loses to
-    serial -- see ``BENCH_codec.json``).  Opt in with
-    ``REPRO_RANS_PROCS=<n>`` to code shards on ``n`` real cores.
-    """
-    env = os.environ.get("REPRO_RANS_PROCS", "").strip()
-    if env:
-        return max(0, int(env))
-    return 0
-
-
-_PROC_POOL = None
-_PROC_SIZE = 0
-
-
-def _shutdown_proc_pool() -> None:
-    global _PROC_POOL, _PROC_SIZE
-    if _PROC_POOL is not None:
-        _PROC_POOL.shutdown(wait=False)
-    _PROC_POOL, _PROC_SIZE = None, 0
-
-
-def proc_map(fn, items, n_procs: int | None = None) -> list:
-    """Map ``fn`` over ``items`` on the rANS process pool (ordered).
-
-    ``fn`` must be a module-level (picklable) function.  Any pool
-    failure -- a worker crash (BrokenProcessPool), fork/pickle errors --
-    tears the pool down and recomputes *everything* serially in-process,
-    so callers always get correct results: the pool is an optimization,
-    never a correctness dependency.
-    """
-    import multiprocessing
-    from concurrent.futures import ProcessPoolExecutor
-
-    global _PROC_POOL, _PROC_SIZE
-    items = list(items)
-    n = proc_workers() if n_procs is None else n_procs
-    n = min(n, len(items))
-    if n <= 1:
-        return [fn(it) for it in items]
-    try:
-        if _PROC_POOL is None or _PROC_SIZE < n:
-            _shutdown_proc_pool()
-            # spawn, not fork: the parent typically has jax's thread
-            # pools running, and forking a multithreaded process can
-            # deadlock; spawn pays a one-off worker import instead.  The
-            # workers import jax with this package but run numpy only:
-            # pin_cpu keeps them off the parent's accelerator
-            from ..hostenv import pin_cpu
-            _PROC_POOL = ProcessPoolExecutor(
-                max_workers=n, mp_context=multiprocessing.get_context(
-                    "spawn"), initializer=pin_cpu)
-            _PROC_SIZE = n
-        return list(_PROC_POOL.map(fn, items))
-    except Exception:
-        _shutdown_proc_pool()
-        return [fn(it) for it in items]
 
 
 def lane_count(total_bits: int) -> int:

@@ -2,9 +2,9 @@
 
 A chip belongs to one process at a time, and the parent of these
 children (a server, a benchmark, the edge half of a demo) may hold it.
-Every child that only runs host code -- socket decoders, cloud workers,
-entropy-coder pools -- is pinned to JAX's CPU backend, so it neither
-fails on the accelerator's lock nor quietly takes another device.
+Every child that only runs host code -- socket decoders, cloud workers
+-- is pinned to JAX's CPU backend, so it neither fails on the
+accelerator's lock nor quietly takes another device.
 """
 
 from __future__ import annotations
@@ -19,9 +19,9 @@ def cpu_env() -> dict:
 
 
 def pin_cpu() -> None:
-    """First call of a host-only ``multiprocessing`` child (or a pool
-    initializer): pin JAX to the CPU before anything initializes a
-    backend.  Importing this package initializes none."""
+    """First call of a host-only ``multiprocessing`` child: pin JAX to
+    the CPU before anything initializes a backend.  Importing this
+    package initializes none."""
     os.environ["JAX_PLATFORMS"] = "cpu"
     import jax
     jax.config.update("jax_platforms", "cpu")

@@ -57,7 +57,6 @@ single entropy call.
 from __future__ import annotations
 
 import dataclasses
-import os
 import struct
 import time
 
@@ -66,7 +65,8 @@ import numpy as np
 
 from ..core import cabac
 from ..core.backend import QuantSpec
-from ..core.codec import _STREAM_META_FMT, FeatureCodec, flush_decoders
+from ..core.codec import (_STREAM_META_FMT, FeatureCodec, flush_decoders,
+                          use_device_entropy)
 from ..core.tiling import TileECSQ, TilePlan
 from ..obs.metrics import MetricsRegistry
 from ..obs.tracing import span
@@ -241,9 +241,7 @@ def encode_tick(items, cfg: TickConfig = TickConfig()
     stats = TickStats(sessions=len(items))
     items = [(codec, np.asarray(x, np.float32)) for codec, x in items]
     coded: list = [None] * len(items)
-    dev = cfg.device_entropy if cfg.device_entropy is not None else (
-        cfg.coder_mode == "auto"
-        and os.environ.get("REPRO_ENTROPY_DEVICE") == "1")
+    dev = use_device_entropy(cfg.device_entropy, cfg.coder_mode)
 
     groups: dict[tuple, list[int]] = {}
     for i, (codec, x) in enumerate(items):

@@ -7,20 +7,17 @@ the frame bound, RESULT_PART frames and a final RESULT); a FEEDBACK
 frame carries receiver-measured link throughput and queue depth for the
 edge-side rate controller.
 
-Two receive disciplines:
-
-* **tick mode** (default, ``tick=TickConfig()``): arriving chunk frames
-  only accumulate (deferred-mode :class:`TensorAssembler`); a bounded
-  tick window (``max_wait_s`` / ``max_chunks``) then drains every
-  pending chunk of every session -- across connections -- through ONE
-  batched entropy call (:class:`~repro.serving.batcher.DecodeBatcher`),
-  and completed tensors finish + run ``tail_fn`` together.  Stream
-  headers are parsed once per distinct (shape, rung) via a shared
-  :class:`~repro.core.codec.HeaderCache`.  Per-tick metrics land in
-  :attr:`counters`.
-* **per-session mode** (``tick=None``): the original path -- chunks
-  entropy-decode on arrival so decode overlaps the transfer (what
-  ``bench_overlap`` measures), one entropy call per session stream.
+One receive discipline, the tick drain: arriving chunk frames only
+accumulate (deferred-mode :class:`TensorAssembler`); a bounded tick
+window (``tick=TickConfig(max_wait_s=, max_chunks=)``) then drains every
+pending chunk of every session -- across connections -- through ONE
+batched entropy call (:class:`~repro.serving.batcher.DecodeBatcher`),
+and completed tensors finish + run ``tail_fn`` together.  With a zero
+window each arriving chunk arms an immediate drain of what has arrived,
+so decode overlaps the rest of the transfer.  Stream headers are parsed
+once per distinct (shape, rung) via a shared
+:class:`~repro.core.codec.HeaderCache`.  Per-tick metrics land in
+:attr:`counters`.
 
 Hardening (see DESIGN.md, "Hardened scale-out serving"):
 
@@ -131,8 +128,7 @@ class CloudServer:
     ``echo_features``: prepend the reconstructed split-layer tensor to
     the RESULT arrays (used by the demo/tests for the bit-exactness
     check and by the loopback serving transport).
-    ``tick``: cross-session batching bounds; ``None`` selects the
-    per-session decode-on-arrival path.
+    ``tick``: bounds of the cross-session tick drain.
     ``header_cache``: share a :class:`HeaderCache` across servers of one
     worker (a fresh one is made per server otherwise).
     ``max_queue``: admission bound -- new sessions beyond this many in
@@ -158,7 +154,7 @@ class CloudServer:
     def __init__(self, *, tail_fn: Callable | None = None,
                  echo_features: bool = False, host: str = "127.0.0.1",
                  port: int = 0, backend=None,
-                 tick: TickConfig | None = _DEFAULT_TICK,
+                 tick: TickConfig = _DEFAULT_TICK,
                  header_cache: HeaderCache | None = None,
                  max_queue: int | None = None,
                  secret: str | None = None,
@@ -399,19 +395,14 @@ class CloudServer:
 
     @property
     def counters(self) -> dict:
-        """Legacy dict view of the ``repro_server_*`` / ``repro_decode_*``
-        registry instruments (the tick=None shape is pinned by
-        tests/test_batcher.py; registry-only telemetry such as
-        decode-error counts on the legacy path lives in
-        :attr:`metrics`)."""
+        """Dict view of the ``repro_server_*`` / ``repro_decode_*``
+        registry instruments (the full set lives in :attr:`metrics`)."""
         self._sync_gauges()
-        c = {"sessions_served": self.sessions_served,
-             "open_connections": self.open_connections}
-        if self.tick is None:
-            return c
         b = self._batcher.counters
         ticks = int(self._m_ticks.value())
-        c.update(
+        return dict(
+            sessions_served=self.sessions_served,
+            open_connections=self.open_connections,
             ticks=ticks,
             batch_occupancy_avg=(self._m_tick_sessions.value()
                                  / max(ticks, 1)),
@@ -427,7 +418,6 @@ class CloudServer:
             resumed_sessions=int(self._m_resumed.value()),
             duplicate_frames=int(self._m_dups.value()),
         )
-        return c
 
     # -- connection handling --------------------------------------------------
 
@@ -561,7 +551,7 @@ class CloudServer:
         # a revived complete session may be the only pending work: make
         # sure a tick drain is scheduled even if every replayed frame
         # dedups away
-        if self.tick is not None and parked is not None and self._ready:
+        if parked is not None and self._ready:
             self._arm_drain_timer()
 
     # -- admission -------------------------------------------------------------
@@ -594,9 +584,6 @@ class CloudServer:
         if frame.session in conn.shed:
             if frame.ftype == FT_END:
                 conn.shed.discard(frame.session)   # stream over, forget it
-            return
-        if self.tick is None:
-            await self._on_tensor_frame_immediate(frame, conn)
             return
         sessions, writer = conn.sessions, conn.writer
         sess = sessions.get(frame.session)
@@ -879,41 +866,3 @@ class CloudServer:
         if sess.obs_key:
             self._m_pending.remove(session=sess.obs_key)
         self._session_closed()
-
-    # -- per-session (tick=None) path -----------------------------------------
-
-    async def _on_tensor_frame_immediate(self, frame,
-                                         conn: _ConnState) -> None:
-        sessions, writer = conn.sessions, conn.writer
-        sess = sessions.get(frame.session)
-        if sess is None:
-            if frame.ftype == FT_HEADER and not await self._admit(frame,
-                                                                  conn):
-                return
-            sess = sessions[frame.session] = _Session(
-                TensorAssembler(backend=self._backend,
-                                header_cache=self._header_cache),
-                obs_key=f"{conn.conn_id}:{frame.session}")
-            self._session_opened()
-        if self._dedup(frame, sess):
-            return
-        t0 = time.perf_counter()
-        tensor = await asyncio.to_thread(sess.assembler.feed, frame)
-        sess.seen_seqs.add(frame.seq)
-        sess.decode_s += time.perf_counter() - t0
-        if tensor is None:
-            return
-        del sessions[frame.session]
-        self._session_closed()
-        self.sessions_served += 1
-        self._m_sessions.inc()
-        self._m_coded.inc(sess.assembler.chunk_bytes)
-        self._m_elems.inc(sess.assembler.n_elems)
-        arrays = [tensor] if self.echo_features else []
-        if self.tail_fn is not None:
-            t0 = time.perf_counter()
-            out = await asyncio.to_thread(self.tail_fn, tensor)
-            sess.decode_s += time.perf_counter() - t0
-            arrays.extend(out if isinstance(out, (list, tuple)) else [out])
-        await self._send_result(sess, frame.session, writer, sessions,
-                                arrays)

@@ -51,12 +51,8 @@ def server_ssl_context(cert: str, key: str | None = None):
 
 
 def build_server(args: argparse.Namespace) -> CloudServer:
-    if args.no_tick:
-        tick = None
-    elif args.tick_ms is None:
-        tick = TickConfig()
-    else:
-        tick = TickConfig(max_wait_s=args.tick_ms / 1e3)
+    tick = (TickConfig() if args.tick_ms is None
+            else TickConfig(max_wait_s=args.tick_ms / 1e3))
     return CloudServer(
         tail_fn=resolve_tail(args.tail) if args.tail else None,
         echo_features=args.echo,
@@ -122,8 +118,6 @@ def build_parser() -> argparse.ArgumentParser:
                    help="echo the reconstructed tensor in RESULT")
     p.add_argument("--tail", default=None, metavar="MODULE:ATTR",
                    help="importable callable to run as the cloud tail")
-    p.add_argument("--no-tick", action="store_true",
-                   help="per-session decode instead of tick batching")
     p.add_argument("--tick-ms", type=float, default=None,
                    help="tick window of the batching drain in ms "
                         "(default: TickConfig's)")

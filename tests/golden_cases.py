@@ -27,7 +27,8 @@ import struct
 import numpy as np
 
 from repro.core import CodecConfig, calibrate
-from repro.core import cabac
+from repro.core import cabac, rans
+from repro.core.binarization import index_to_context_bits
 from repro.core.codec import (FeatureCodec, _CHANNEL_EXT_FMT, _HEADER_FMT,
                               FLAG_CHANNEL, FLAG_V2)
 from repro.core.ecsq import ECSQQuantizer
@@ -82,6 +83,38 @@ def build_v2_channel_stream(x: np.ndarray, lo: np.ndarray, hi: np.ndarray,
     header += np.stack([lo, hi], axis=-1).astype("<f4").tobytes()
     return header + cabac.encode_indices(idx.ravel(), n_levels,
                                          mode="rans")
+
+
+def build_sharded_payload(idx: np.ndarray, n_levels: int, n_shards: int,
+                          coder_id: int = 2) -> bytes:
+    """An entropy payload in the sharded layout of coder ids 2 and 3:
+    id byte | <H> n_shards | n_shards x <I> byte length | shard streams,
+    each an independent ``rans.encode_planes`` stream over one contiguous
+    element range of ``ceil(n / n_shards)`` elements (the last may be
+    short; ranges past the end are left out).  The thread- (2) and
+    process-pool (3) encoders that wrote it are retired, so both ids are
+    decode-only -- this helper freezes their layout.  Coder 4 is the
+    same layout at one shard."""
+    idx = np.asarray(idx).ravel()
+    per = -(-idx.size // n_shards)
+    blobs = [rans.encode_planes(index_to_context_bits(idx[a:a + per],
+                                                      n_levels))
+             for a in range(0, idx.size, max(per, 1))]
+    return (bytes([coder_id]) + struct.pack("<H", len(blobs))
+            + struct.pack(f"<{len(blobs)}I", *[len(b) for b in blobs])
+            + b"".join(blobs))
+
+
+def build_v2_sharded_stream(x: np.ndarray, cmin: float, cmax: float,
+                            n_levels: int, n_shards: int,
+                            coder_id: int) -> bytes:
+    """A v2 per-tensor stream whose payload is a coder-2 or coder-3
+    sharded container (decode-only since those encoders were retired)."""
+    from repro.core.backend import QuantSpec, get_backend
+    idx = np.asarray(get_backend("jnp").quantize(x, QuantSpec(
+        float(cmin), float(cmax), n_levels)))
+    header = struct.pack(_HEADER_FMT, cmin, cmax, n_levels, FLAG_V2, x.size)
+    return header + build_sharded_payload(idx, n_levels, n_shards, coder_id)
 
 
 def _v2_uniform_codec(n_levels=4):
@@ -204,4 +237,11 @@ CASES = [
          lambda x: _v4_tile2d_codec(x, use_ecsq=True)),
     Case("v4_tile2d_stream", _conv_input, _v4_tile2d_codec, streamed=True,
          chunk_elems=64),
+    Case("v2_rans_shard2", _flat_input, _receiver, decode_only=True,
+         builder=lambda x: build_v2_sharded_stream(x, 0.0, 9.0, 4,
+                                                   n_shards=4, coder_id=2)),
+    Case("v2_rans_proc3", lambda: _flat_input(n=2999), _receiver,
+         decode_only=True,
+         builder=lambda x: build_v2_sharded_stream(x, 0.0, 9.0, 4,
+                                                   n_shards=3, coder_id=3)),
 ]

@@ -329,23 +329,6 @@ class TestServerTick:
         assert counters["queue_depth"] == 0     # A's decoder was purged
         assert counters["decode_errors"] == 0
 
-    def test_legacy_path_unchanged(self, features):
-        codec = _live_codec(features)
-
-        async def run():
-            async with CloudServer(echo_features=True, tick=None) as srv:
-                async with EdgeClient("127.0.0.1", srv.port, codec=codec,
-                                      chunk_elems=600) as client:
-                    res = await client.submit(features)
-                    return res, srv.counters
-
-        res, counters = asyncio.run(run())
-        ref = codec.decode(codec.encode(features), shape=features.shape)
-        np.testing.assert_array_equal(np.asarray(res.arrays[0]), ref)
-        # legacy counters stay minimal: no tick metrics
-        assert counters["sessions_served"] == 1
-        assert set(counters) == {"sessions_served", "open_connections"}
-
 
 class TestClientTick:
     def test_coalesced_submits_bit_exact(self, features):

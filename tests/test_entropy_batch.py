@@ -1,35 +1,24 @@
-"""Batched entropy decode + process-pool shard coder tests.
+"""Batched entropy coding tests.
 
 ``decode_indices_batch`` must be result-identical to per-payload
-``decode_indices`` for any mix of coders; the process-pool coder
-(id 3) must round-trip, share the thread-sharded byte layout, and fall
-back in-process -- byte-identically -- when the pool breaks.
+``decode_indices`` for any mix of coders, and ``encode_indices_batch``
+must pick each segment's coder by the same ``auto`` rule as
+``encode_indices``.
 """
 
-import os
+import pathlib
+import sys
 
 import numpy as np
 import pytest
 
 from repro.core import cabac, rans
 from repro.core import CodecConfig, calibrate
-from repro.core.binarization import index_to_context_bits
+from repro.core.binarization import index_to_context_bits, total_tu_bits
 
+sys.path.insert(0, str(pathlib.Path(__file__).resolve().parent))
 
-@pytest.fixture(autouse=True)
-def _clean_pools():
-    # restore (not just pop) the pool env: the CI entropy_coders matrix
-    # exports these for the whole pytest run, and later test files must
-    # still see them -- only values set *by a test here* are undone
-    before = {k: os.environ.get(k)
-              for k in ("REPRO_RANS_PROCS", "REPRO_RANS_THREADS")}
-    yield
-    for k, v in before.items():
-        if v is None:
-            os.environ.pop(k, None)
-        else:
-            os.environ[k] = v
-    rans._shutdown_proc_pool()
+from golden_cases import build_sharded_payload  # noqa: E402
 
 
 class TestBatchPlaneDecoder:
@@ -95,8 +84,7 @@ class TestDecodeIndicesBatch:
                 rng.choice(8, size=200000).astype(np.int32),
                 rng.choice(2, size=70000).astype(np.int32)]
         blobs = [cabac.encode_indices(s, 8) for s in segs]
-        os.environ["REPRO_RANS_THREADS"] = "2"
-        blobs.append(cabac.encode_indices(segs[2], 8, mode="rans_sharded"))
+        blobs.append(build_sharded_payload(segs[2], 8, n_shards=2))
         segs.append(segs[2])
         out = cabac.decode_indices_batch(blobs, [s.size for s in segs], 8)
         for s, o in zip(segs, out):
@@ -167,59 +155,27 @@ class TestStreamBatchedDecode:
             dec.finish()
 
 
-class TestProcessPoolCoder:
-    def test_round_trip_and_auto_selection(self):
-        os.environ["REPRO_RANS_PROCS"] = "2"
-        rng = np.random.default_rng(0)
-        idx = rng.choice(4, size=1 << 21).astype(np.int32)
-        blob = cabac.encode_indices(idx, 4, mode="auto")
-        assert blob[0] == cabac._CODER_RANS_PROC
-        np.testing.assert_array_equal(
-            cabac.decode_indices(blob, idx.size, 4), idx)
+class TestAutoRule:
+    """``encode_indices`` and ``encode_indices_batch`` share one ``auto``
+    rule: serial CABAC (id 0) below ``_SERIAL_CUTOFF_BITS`` TU bits,
+    rANS (id 1) at or above it."""
 
-    def test_decodes_without_pool_configured(self):
-        os.environ["REPRO_RANS_PROCS"] = "2"
-        rng = np.random.default_rng(1)
-        idx = rng.choice(4, size=300000).astype(np.int32)
-        blob = cabac.encode_indices(idx, 4, mode="rans_proc")
-        del os.environ["REPRO_RANS_PROCS"]
-        rans._shutdown_proc_pool()
-        np.testing.assert_array_equal(
-            cabac.decode_indices(blob, idx.size, 4), idx)
-
-    def test_shard_bytes_match_thread_coder(self):
-        os.environ["REPRO_RANS_PROCS"] = "2"
-        os.environ["REPRO_RANS_THREADS"] = "2"
-        rng = np.random.default_rng(2)
-        idx = rng.choice(4, size=400000).astype(np.int32)
-        proc = cabac.encode_indices(idx, 4, mode="rans_proc")
-        thread = cabac.encode_indices(idx, 4, mode="rans_sharded")
-        assert proc[0] == 3 and thread[0] == 2
-        assert proc[1:] == thread[1:]
-
-    def test_worker_crash_falls_back_byte_identical(self):
-        os.environ["REPRO_RANS_PROCS"] = "2"
-        rng = np.random.default_rng(3)
-        idx = rng.choice(4, size=300000).astype(np.int32)
-        good = cabac.encode_indices(idx, 4, mode="rans_proc")
-
-        class BrokenPool:
-            def map(self, *a, **k):
-                raise RuntimeError("worker died")
-
-            def shutdown(self, wait=False):
-                pass
-
-        rans._PROC_POOL = BrokenPool()
-        rans._PROC_SIZE = 99
-        fallback = cabac.encode_indices(idx, 4, mode="rans_proc")
-        assert fallback == good          # serial fallback, same bytes
-        assert rans._PROC_POOL is None   # broken pool was torn down
-        rans._PROC_POOL = BrokenPool()
-        rans._PROC_SIZE = 99
-        np.testing.assert_array_equal(
-            cabac.decode_indices(good, idx.size, 4), idx)
-        assert rans._PROC_POOL is None
+    @pytest.mark.parametrize("n,n_levels,coder_id", [
+        (0, 4, 0),
+        (5_000, 4, 0),
+        (65_535, 2, 0),         # one TU bit an element: just under
+        (40_000, 8, 1),         # TU bits above the cutoff, fewer elements
+        (1 << 16, 2, 1),        # the element count alone settles it
+    ])
+    def test_same_coder_either_side_of_cutoff(self, n, n_levels, coder_id):
+        rng = np.random.default_rng(n)
+        seg = rng.integers(0, n_levels, n).astype(np.int32)
+        bits = total_tu_bits(seg, n_levels)
+        assert (bits >= cabac._SERIAL_CUTOFF_BITS) == bool(coder_id)
+        one = cabac.encode_indices(seg, n_levels)
+        (batch,) = cabac.encode_indices_batch([seg], n_levels)
+        assert one[0] == batch[0] == coder_id
+        assert one == batch
 
 
 class TestEncoderCompaction:

@@ -292,10 +292,12 @@ class TestServerTelemetry:
                        frozenset())] == 1.0
 
     def test_legacy_tick_none_registry_counts_errors(self, features):
+        """A protocol error is counted in the registry and in the one
+        ``counters`` shape (the server has one receive discipline)."""
         codec = _live_codec(features)
 
         async def run():
-            async with CloudServer(echo_features=True, tick=None) as srv:
+            async with CloudServer(echo_features=True) as srv:
                 async with EdgeClient("127.0.0.1", srv.port, codec=codec,
                                       chunk_elems=600) as client:
                     await client.submit(features)
@@ -314,10 +316,16 @@ class TestServerTelemetry:
                 return srv.counters, srv.metrics.snapshot()
 
         counters, snap = asyncio.run(run())
-        # the legacy dict shape is pinned: registry-only telemetry must
-        # not leak new keys into it
-        assert set(counters) == {"sessions_served", "open_connections"}
+        # the dict shape is pinned: registry-only telemetry must not
+        # leak new keys into it
+        assert set(counters) == {
+            "sessions_served", "open_connections", "ticks",
+            "batch_occupancy_avg", "queue_depth", "entropy_calls",
+            "entropy_chunks", "entropy_melem_per_s", "bpe_avg",
+            "decode_errors", "header_cache", "shed_sessions",
+            "resumed_sessions", "duplicate_frames"}
         assert counters["sessions_served"] == 1
+        assert counters["decode_errors"] == 1
         assert _series(snap,
                        "repro_server_sessions_served_total")["value"] == 1
         assert _series(snap,
@@ -416,7 +424,7 @@ class TestServerTelemetry:
         codec = _live_codec(features)
 
         async def run():
-            async with CloudServer(echo_features=True, tick=None) as srv:
+            async with CloudServer(echo_features=True) as srv:
                 reader, writer = await asyncio.open_connection(
                     "127.0.0.1", srv.port)
                 writer.write(encode_frame(FT_METRICS, 0, 0, b""))

@@ -120,6 +120,10 @@ class TestGoldenCoverage:
         rans = (GOLDEN_DIR / "v2_uniform_rans.stream.bin").read_bytes()
         hdr = parse_header(rans)
         assert rans[hdr.payload_off] == 1            # vectorized rANS
+        # decode-only sharded containers
+        for name, coder in (("v2_rans_shard2", 2), ("v2_rans_proc3", 3)):
+            stream = (GOLDEN_DIR / f"{name}.stream.bin").read_bytes()
+            assert stream[parse_header(stream).payload_off] == coder
 
 
 _ENCODABLE = [c for c in CASES if not c.decode_only]
@@ -134,14 +138,14 @@ def _with_backend(codec, backend):
 
 def _host_single_shard(codec, x):
     """The host reference for coder 4: coder-2 layout with exactly one
-    shard over the same coded-order indices."""
+    shard (``rans.encode_planes``) over the same coded-order indices."""
     import jax.numpy as jnp
 
-    from repro.core import cabac
+    from golden_cases import build_sharded_payload
 
     coded = np.asarray(codec.backend.coded_indices_device(
         jnp.asarray(x), codec.spec(), codec.bits_per_index()))
-    return cabac._encode_rans_sharded(coded, codec.config.n_levels, 1)
+    return build_sharded_payload(coded, codec.config.n_levels, 1)
 
 
 @pytest.mark.parametrize("backend", _BACKENDS)

@@ -3,19 +3,25 @@
 edge<->cloud session layer."""
 
 import asyncio
+import pathlib
 import struct
+import sys
 
 import numpy as np
 import pytest
 
 from repro.core import CodecConfig, calibrate
-from repro.core import cabac, rans
+from repro.core import cabac
 from repro.transport import (CloudServer, CodecBank, EdgeClient, Frame,
                              FrameReader, FramingError, RateControlConfig,
                              RateController, ResultAssembler,
                              TensorAssembler, TransportError, encode_frame,
                              framing, pack_arrays, result_frames,
                              tensor_to_frames, unpack_arrays)
+
+sys.path.insert(0, str(pathlib.Path(__file__).resolve().parent))
+
+from golden_cases import build_sharded_payload  # noqa: E402
 
 
 @pytest.fixture(scope="module")
@@ -34,7 +40,7 @@ def _codec(features, granularity="tensor", n_levels=4, **kw):
 
 class TestChunkedStream:
     @pytest.mark.parametrize("granularity", ["tensor", "channel"])
-    @pytest.mark.parametrize("mode", ["serial", "rans", "rans_sharded"])
+    @pytest.mark.parametrize("mode", ["serial", "rans"])
     def test_bit_exact_with_one_shot(self, features, granularity, mode):
         codec = _codec(features, granularity)
         one = codec.decode(codec.encode(features, coder_mode=mode),
@@ -160,43 +166,18 @@ class TestFraming:
 
 
 class TestShardedRans:
+    """Coder ids 2 and 3 are decode-only: their containers come from the
+    test-side layout builder (``golden_cases.build_sharded_payload``)."""
+
+    @pytest.mark.parametrize("coder_id", [2, 3])
     @pytest.mark.parametrize("n", [0, 1, 7, 4096, 100_001])
-    def test_round_trip(self, n):
+    def test_round_trip(self, n, coder_id):
         rng = np.random.default_rng(n)
         idx = rng.integers(0, 4, n).astype(np.int32)
-        blob = cabac.encode_indices(idx, 4, mode="rans_sharded")
+        blob = build_sharded_payload(idx, 4, n_shards=3, coder_id=coder_id)
+        assert blob[0] == coder_id
         np.testing.assert_array_equal(
             cabac.decode_indices(blob, n, 4), idx)
-
-    def test_thread_override(self, monkeypatch):
-        rng = np.random.default_rng(0)
-        idx = rng.integers(0, 8, 50_000).astype(np.int32)
-        monkeypatch.setenv("REPRO_RANS_THREADS", "3")
-        assert rans.rans_threads() == 3
-        blob3 = cabac.encode_indices(idx, 8, mode="rans_sharded")
-        # streams decode under any thread configuration
-        monkeypatch.setenv("REPRO_RANS_THREADS", "1")
-        np.testing.assert_array_equal(
-            cabac.decode_indices(blob3, idx.size, 8), idx)
-        blob1 = cabac.encode_indices(idx, 8, mode="rans_sharded")
-        monkeypatch.setenv("REPRO_RANS_THREADS", "4")
-        np.testing.assert_array_equal(
-            cabac.decode_indices(blob1, idx.size, 8), idx)
-
-    def test_auto_mode_selects_sharded_only_with_threads(self, monkeypatch):
-        rng = np.random.default_rng(1)
-        idx = rng.integers(0, 4, 2_000_000).astype(np.int32)
-        # the proc pool outranks the thread pool in auto mode: pin this
-        # test's coder choice regardless of the CI coder-matrix env
-        monkeypatch.delenv("REPRO_RANS_PROCS", raising=False)
-        monkeypatch.setenv("REPRO_RANS_THREADS", "1")
-        assert cabac.encode_indices(idx, 4, mode="auto")[0] \
-            == cabac._CODER_RANS
-        monkeypatch.setenv("REPRO_RANS_THREADS", "2")
-        blob = cabac.encode_indices(idx, 4, mode="auto")
-        assert blob[0] == cabac._CODER_RANS_SHARDED
-        np.testing.assert_array_equal(
-            cabac.decode_indices(blob, idx.size, 4), idx)
 
 
 class TestRateControl:
