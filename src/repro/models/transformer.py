@@ -161,11 +161,19 @@ def init_cache(cfg: ModelConfig, batch: int, max_seq: int, split: bool = False):
     return caches
 
 
-def _align_param_groups(params, groups):
-    """Slice stacked layer params to match a (possibly split) group layout.
+def _align_param_groups(params, groups, sliced: bool = False):
+    """Match the stacked layer params to a (possibly split) group layout.
 
     Lets the same params pytree serve both the unsplit and the codec-split
-    execution paths: splitting a scan group is a zero-copy slice under jit.
+    execution paths.  A group carved out of a longer stack gets the whole
+    stack plus its first layer index (``"start"``), and :func:`_apply_group`
+    reads each of its layers in place: slicing ``a[lo:lo + n]`` out of a
+    jit argument is a copy of those layers on every call, not a view.
+
+    ``sliced`` takes that copy instead, for the gradient path: there a
+    slice transposes to one pad, whereas an in-place read inside the scan
+    carries a whole-stack cotangent through each carved group (about 1.5x
+    the temporaries; pinned in ``tests/test_tpu_compile.py``).
     """
     gp = params["groups"]
     if len(gp) == len(groups):
@@ -177,8 +185,11 @@ def _align_param_groups(params, groups):
     offset = 0
     for g in groups[:n_from_main]:
         lo = offset
-        out.append({"layers": jax.tree.map(
-            lambda a: a[lo:lo + g.n_periods], main["layers"])})
+        if sliced:
+            out.append({"layers": jax.tree.map(
+                lambda a: a[lo:lo + g.n_periods], main["layers"])})
+        else:
+            out.append({"layers": main["layers"], "start": lo})
         offset += g.n_periods
     out.extend(src)
     return out
@@ -343,22 +354,44 @@ def _remat_group_size(n: int) -> int:
 
 def _apply_group(x, gparams, group: Group, cfg: ModelConfig, *, pos, gcache,
                  ctx, positions, remat: bool = False):
-    """Scan one group of n_periods over stacked params/caches."""
-    if remat and gcache is None and group.n_periods >= 64:
+    """Scan one group of n_periods over stacked params/caches.
+
+    ``gparams["layers"]`` is either the group's own stack, scanned as
+    ``xs``, or, when ``gparams`` has a ``"start"``, a longer stack the group
+    was carved from (first layer at that index).  Then the scan runs over
+    layer indices and each step indexes its layer out of the whole stack,
+    which reads the weights in place instead of copying the group's layers
+    first."""
+    layers = gparams["layers"]
+    n = group.n_periods
+    start = gparams.get("start")
+    if start is None:
+        xs_layers = layers
+
+        def take(pp):
+            return pp
+    else:
+        xs_layers = jnp.arange(start, start + n)
+
+        def take(i):
+            return jax.tree.map(
+                lambda a: lax.dynamic_index_in_dim(a, i, keepdims=False),
+                layers)
+
+    if remat and gcache is None and n >= 64:
         # sqrt-remat pays off only when the residual-carry stack dominates
         # (deep stacks); for shallow models the recompute traffic regressed
         # the memory term in the dry-run (see EXPERIMENTS §Perf).
-        g = _remat_group_size(group.n_periods)
+        g = _remat_group_size(n)
         if g > 1:
-            n1 = group.n_periods // g
             lay = jax.tree.map(
-                lambda a: a.reshape(n1, g, *a.shape[1:]), gparams["layers"])
+                lambda a: a.reshape(n // g, g, *a.shape[1:]), xs_layers)
 
             @jax.checkpoint
             def super_body(carry, pp):
                 xc = carry
                 for j in range(g):
-                    pj = jax.tree.map(lambda a: a[j], pp)
+                    pj = take(jax.tree.map(lambda a: a[j], pp))
                     for si, spec in enumerate(group.specs):
                         xc, _ = _apply_layer(xc, pj[si], spec, cfg, pos=pos,
                                              cache=None, ctx=ctx,
@@ -371,6 +404,7 @@ def _apply_group(x, gparams, group: Group, cfg: ModelConfig, *, pos, gcache,
     def period_body(carry, xs):
         xc = carry
         pp, cc = xs
+        pp = take(pp)
         new_cc = [] if cc is not None else None
         for j, spec in enumerate(group.specs):
             xc, ncj = _apply_layer(
@@ -384,10 +418,10 @@ def _apply_group(x, gparams, group: Group, cfg: ModelConfig, *, pos, gcache,
     body = jax.checkpoint(period_body) if remat else period_body
     if gcache is None:
         x, _ = lax.scan(lambda c, xs: (body(c, (xs, None))[0], None),
-                        x, gparams["layers"])
+                        x, xs_layers)
         return x, None
     x, new_cache = lax.scan(lambda c, xs: body(c, xs),
-                            x, (gparams["layers"], gcache))
+                            x, (xs_layers, gcache))
     return x, new_cache
 
 
@@ -488,7 +522,7 @@ def forward_from_boundary(cfg: ModelConfig, params, x, *,
 def _hidden_forward(cfg, params, batch_in, *, ctx, codec_fn, split, remat):
     """Backbone only: returns final hidden states (B, S, d) + aux."""
     groups, boundary = build_groups(cfg, split or codec_fn is not None)
-    pgroups = _align_param_groups(params, groups)
+    pgroups = _align_param_groups(params, groups, sliced=True)
     x = _embed_in(cfg, params, batch_in, ctx=ctx)
     positions = jnp.arange(x.shape[1], dtype=jnp.int32)
     aux = {}
